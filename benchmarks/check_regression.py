@@ -210,7 +210,13 @@ def check_mesh(base, cur, floor, eff, frac, failures):
             "mesh regression: sharded evaluation no longer bit-identical "
             "to the solo jit path")
     cores = max(1, int(cur.get("usable_cores", 1)))
-    max_shards = max(1, int(cur.get("max_shards", 8)))
+    max_shards = int(cur.get("max_shards", 8))
+    if max_shards < 2:
+        failures.append(
+            f"mesh benchmark measured no sharding (max_shards="
+            f"{max_shards}); run python -m benchmarks.mesh in a fresh "
+            f"process")
+        return
     need = max(floor, eff * min(max_shards, cores))
     speedup = cur.get("geomean_speedup_8v1", 0.0)
     if speedup < need:

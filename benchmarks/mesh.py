@@ -5,11 +5,16 @@ backend (``backend="mesh"``, docs/mesh.md) at 1/2/4/8 shards of an
 8-device host-platform CPU mesh, against the solo jit fixpoint —
 asserting bit-identical results at every shard count.
 
-Device count is fixed at jax backend initialization, so this benchmark
-needs ``--xla_force_host_platform_device_count=8`` set before jax's
-first computation.  Run standalone it arranges that itself; invoked from
-``benchmarks.run`` (where earlier benchmarks already initialized jax on
-1 device) it re-execs itself in a subprocess with the flag set.
+Device count is fixed at jax backend initialization, so on a CPU host
+this benchmark needs ``--xla_force_host_platform_device_count=8`` set
+before jax's first computation; :func:`run` arms it while jax is still
+uninitialized.  It never starts a child process: a chip belongs to one
+process, and a parent that has touched jax holds it.  A process whose
+jax already initialized with fewer devices (``benchmarks.run`` after
+earlier steps, or a TPU host) measures over the shard counts its devices
+allow, recorded as ``max_shards``; with a single device there is no
+sharding to measure, so the step reports itself skipped (and saves
+nothing) instead — run ``python -m benchmarks.mesh`` in a fresh process.
 
 Scaling expectations are host-dependent: host-platform devices are
 threads, so wall-clock speedup is bounded by real cores.  The recorded
@@ -22,20 +27,12 @@ its OWN slowest row converges instead of the global worst case.
 
 from __future__ import annotations
 
-import json
 import os
-import subprocess
-import sys
 from typing import Dict
-
-if "jax" not in sys.modules:     # standalone: arm the flag pre-import
-    from repro.launch.mesh import ensure_host_platform_devices
-    ensure_host_platform_devices(8)
 
 import numpy as np
 
-from benchmarks.common import (RESULTS_DIR, Timer, geomean, quick_mode,
-                               save_json)
+from benchmarks.common import Timer, geomean, quick_mode, save_json
 
 SHARD_COUNTS = (1, 2, 4, 8)
 MAX_SHARDS = SHARD_COUNTS[-1]
@@ -64,14 +61,21 @@ def _bench(ev, cfgs, reps: int):
 
 
 def _measure(seed: int = 0) -> Dict:
+    import jax
     from repro.core import EvalConfig, build_simgraph
     from repro.core.simulate import BatchedEvaluator
     from repro.designs import make_design
 
     C = 64 if quick_mode() else 256
     reps = 2 if quick_mode() else 3
+    if jax.device_count() < 2:
+        return {"skipped": f"{jax.device_count()} device in this process "
+                           f"and no sharding to measure; run python -m "
+                           f"benchmarks.mesh in a fresh process"}
+    shard_counts = [s for s in SHARD_COUNTS if s <= jax.device_count()]
+    max_shards = shard_counts[-1]
     out: Dict = {"designs": {}, "batch": C,
-                 "max_shards": MAX_SHARDS,
+                 "max_shards": max_shards,
                  "usable_cores": os.cpu_count() or 1}
     speedups = []
     identical_all = True
@@ -87,7 +91,7 @@ def _measure(seed: int = 0) -> Dict:
         row: Dict = {"solo_us_per_config": round(1e6 * t_solo / C, 1),
                      "shards": {}}
         t_by_shards = {}
-        for s in SHARD_COUNTS:
+        for s in shard_counts:
             t_s, r_s = _bench(
                 BatchedEvaluator(g, EvalConfig(backend="mesh", max_iters=64,
                                                shards=s),
@@ -102,13 +106,13 @@ def _measure(seed: int = 0) -> Dict:
         # production-path identity too: full cascade, sharded vs solo
         ev_m = BatchedEvaluator(
             g, EvalConfig(backend="mesh", max_iters=64,
-                          shards=MAX_SHARDS))
+                          shards=max_shards))
         ev_j = BatchedEvaluator(g, EvalConfig(backend="jax", max_iters=64))
         identical = all((a == b).all() for a, b in
                         zip(ev_j.evaluate(cfgs), ev_m.evaluate(cfgs)))
         identical_all &= identical
         row["cascade_identical"] = identical
-        speedup = t_by_shards[1] / max(t_by_shards[MAX_SHARDS], 1e-12)
+        speedup = t_by_shards[1] / max(t_by_shards[max_shards], 1e-12)
         row["speedup_8v1"] = round(speedup, 2)
         speedups.append(speedup)
         out["designs"][name] = row
@@ -118,40 +122,32 @@ def _measure(seed: int = 0) -> Dict:
 
 
 def run(seed: int = 0) -> Dict:
-    """Measure (re-execing under an 8-device mesh if needed) and save."""
-    import jax
-    if jax.device_count() < MAX_SHARDS:
-        # jax already initialized on fewer devices (benchmarks.run
-        # imports it long before us): measure in a fresh process
-        env = dict(os.environ)
-        flag = f"--xla_force_host_platform_device_count={MAX_SHARDS}"
-        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + flag).strip()
-        proc = subprocess.run(
-            [sys.executable, "-m", "benchmarks.mesh"],
-            env=env, capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise RuntimeError(
-                f"mesh benchmark subprocess failed:\n{proc.stderr}")
-        name = "mesh.quick.json" if quick_mode() else "mesh.json"
-        with open(os.path.join(RESULTS_DIR, name)) as f:
-            return json.load(f)
+    """Measure in this process over the devices it has, and save."""
+    from repro.launch.mesh import ensure_host_platform_devices
+    ensure_host_platform_devices(MAX_SHARDS)   # no-op once jax is up
     out = _measure(seed)
-    save_json("mesh.json", out)
+    if "skipped" not in out:
+        save_json("mesh.json", out)
     return out
 
 
-def main():
+def main() -> int:
     out = run()
+    if "skipped" in out:
+        print(f"mesh benchmark skipped: {out['skipped']}")
+        return 1
     for name, d in out["designs"].items():
         cols = "  ".join(f"s{s}={v['configs_per_s']:.0f}/s"
                          for s, v in d["shards"].items())
         print(f"{name:14s} solo={d['solo_us_per_config']}us {cols} "
               f"8v1={d['speedup_8v1']}x "
               f"identical={d['cascade_identical']}")
-    print(f"geomean 8v1 speedup {out['geomean_speedup_8v1']}x on "
+    print(f"geomean {out['max_shards']}v1 speedup "
+          f"{out['geomean_speedup_8v1']}x on "
           f"{out['usable_cores']} core(s), "
           f"identical={out['identical_all']}")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

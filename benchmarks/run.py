@@ -85,6 +85,8 @@ def _condense() -> str:
 def _mesh() -> str:
     from benchmarks import mesh
     ms = mesh.run()
+    if "skipped" in ms:
+        return f"skipped: {ms['skipped']}"
     return (f"speedup_8v1={ms['geomean_speedup_8v1']:.2f}x;"
             f"cores={ms['usable_cores']};"
             f"identical={ms['identical_all']}")

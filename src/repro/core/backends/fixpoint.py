@@ -21,7 +21,8 @@ in the inner fixpoint implementation:
 ``FixpointBackend``  ``lax.associative_scan`` + ``lax.while_loop`` in stock
                      jnp (the TPU-native formulation, DESIGN.md §6)
 ``PallasBackend``    the hand-rolled Hillis-Steele kernel in
-                     :mod:`repro.kernels.fifo_eval` (interpret mode on CPU)
+                     :mod:`repro.kernels.fifo_eval` (Mosaic-compiled on a
+                     TPU, interpreted on the CPU)
 
 Numeric domain: times are exact in float32 while below 2**24; the façade
 asserts the design's schedule upper bound stays below ~1.5e7 cycles.
@@ -48,7 +49,6 @@ class _ScanBackend(EvalBackend):
     """Common wrapper: shared operands + one jitted batched callable."""
 
     use_ref = True
-    interpret = True
     wants_bucketing = True
     #: a jax.sharding.Mesh to shard the config-row axis over (None = solo
     #: jit on the default device); set by the MeshBackend subclass
@@ -74,8 +74,8 @@ class _ScanBackend(EvalBackend):
         self.g = g
         self.ops = get_operands(g)
         self._call = make_batched_eval(
-            g, interpret=self.interpret, use_ref=self.use_ref,
-            max_iters=self.max_iters, mesh=self.mesh)
+            g, use_ref=self.use_ref, max_iters=self.max_iters,
+            mesh=self.mesh)
         self._call_times = None
         # kernel-backed backends prepared on a CondensedGraph fuse the
         # exactness certificate into the evaluation launch (the rung
@@ -92,8 +92,7 @@ class _ScanBackend(EvalBackend):
             if (isinstance(g, CondensedGraph)
                     and g.compression >= FUSED_MIN_COMPRESSION):
                 self._fused = make_condensed_eval(
-                    g, interpret=self.interpret, max_iters=self.max_iters,
-                    mesh=self.mesh)
+                    g, max_iters=self.max_iters, mesh=self.mesh)
         return self.ops
 
     @property
@@ -134,8 +133,8 @@ class _ScanBackend(EvalBackend):
         if self._call_times is None:
             from repro.kernels.fifo_eval.ops import make_batched_eval
             self._call_times = make_batched_eval(
-                self.g, interpret=self.interpret, use_ref=self.use_ref,
-                max_iters=self.max_iters, with_times=True, mesh=self.mesh)
+                self.g, use_ref=self.use_ref, max_iters=self.max_iters,
+                with_times=True, mesh=self.mesh)
         m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int32))
         m, c = self._pad_shards(m)
         lat, bram, status, times = self._call_times(m)

@@ -50,9 +50,8 @@ import jax.numpy as jnp
 from repro.core.backends.jaxcfg import configure_jax
 from repro.core.bram import BRAM18K_CONFIGS, SRL_BITS, SRL_DEPTH
 
-# arm the opt-in persistent compilation cache (REPRO_JIT_CACHE_DIR)
-# before any backend's first jit trace — this module is the first jax
-# import on every backend path
+# arm the persistent compilation cache before any backend's first jit
+# trace — this module is the first jax import on every backend path
 configure_jax()
 from repro.core.design import READ, WRITE
 from repro.core.simgraph import SimGraph

@@ -18,7 +18,8 @@ subsystem in :mod:`repro.core.backends`:
         the task segments coupled to the changed FIFOs.
     ``"jax"`` (alias ``"fixpoint"``) — jit(vmap) Jacobi + segmented-scan
         fixpoint; the TPU-native formulation (DESIGN.md §6).
-    ``"pallas"`` — the ``kernels/fifo_eval`` kernel (interpret mode on CPU).
+    ``"pallas"`` — the ``kernels/fifo_eval`` kernels (Mosaic-compiled on a
+        TPU, interpreted on the CPU).
 
     Batch bucketing, jit-cache reuse, and tiered UNRESOLVED-row escalation
     to the worklist live in :class:`repro.core.backends.DispatchPolicy`.

@@ -1,7 +1,8 @@
 """Batched FIFO-configuration latency evaluation (Pallas TPU kernel).
 
-``fifo_eval.py``  pl.pallas_call kernel (BlockSpec VMEM tiling, one grid
-                  program per candidate configuration).
+``fifo_eval.py``  raw pl.pallas_call kernel (BlockSpec VMEM tiling, one
+                  grid program per 8-row block of configurations).
+``condensed.py``  fused condensed kernel: fixpoint + exactness certificate.
 ``ops.py``        jit'd wrapper: SimGraph -> padded event tensors -> kernel.
 ``ref.py``        pure-jnp oracle with identical semantics.
 """

@@ -1,13 +1,11 @@
 """Pallas TPU kernel: condensation-native fused evaluation + certificate.
 
-The raw kernel (:mod:`repro.kernels.fifo_eval.fifo_eval`) launches one
-grid program per configuration over (1, E) vectors — the right shape at
-E = 8k-13k raw events.  Post-condensation the hot rungs run at
-Ec = 64-512 anchors (25-150x compression), where a one-row program
-wastes the vector unit and, worse, the exactness certificate
-(``condense.verify_rows``) used to run on the HOST: every batch paid a
-device->host transfer of the (C, E_pad) event-time matrix plus an
-O(C x E_raw) int64 expansion just to decide which rows to accept.
+The raw kernel (:mod:`repro.kernels.fifo_eval.fifo_eval`) runs 8-row
+blocks at raw width (E = 8k-33k events).  Post-condensation the hot rungs
+run at Ec = 128-3328 anchors (10-110x compression), and the exactness
+certificate (``condense.verify_rows``) used to run on the HOST: every
+batch paid a device->host transfer of the (C, E_pad) event-time matrix
+plus an O(C x E_raw) int64 expansion just to decide which rows to accept.
 
 This kernel owns the whole rung on-device:
 
@@ -16,11 +14,10 @@ This kernel owns the whole rung on-device:
   operands arrive as (BLOCK, Ec_pad) tiles and certificate slots as
   (BLOCK, V_pad) tiles; Pallas's BlockSpec pipeline streams consecutive
   tiles through VMEM, double-buffering the HBM copies against compute.
-* **per-row fixpoint** — the same Jacobi + segmented Hillis-Steele scan
-  as the raw kernel, but batched over the block with per-row freezing:
-  converged / over-bound rows stop updating (and stop counting
-  iterations) while the rest of the block keeps stepping, so easy rows
-  do not ride along for the block's worst case.
+* **per-row fixpoint** — the raw kernel's Jacobi + segmented
+  Hillis-Steele scan (shared code), batched over the block with per-row
+  freezing: converged / over-bound rows stop updating while the rest of
+  the block keeps stepping.
 * **fused certificate** — after the fixpoint, the dropped cross
   constraints of every folded event are checked as flat gather slots
   (``t[src] - t[dst] > thr``, see
@@ -33,8 +30,8 @@ construction), so the in-kernel f32 certificate is bit-for-bit equal to
 the int64 host verifier — property-tested in
 ``tests/test_condensed_kernel.py``.
 
-Validated in ``interpret=True`` mode on CPU (the container has no TPU);
-pass ``interpret=False`` on real hardware.
+Compiled by Mosaic on a TPU, interpreted on the CPU (tests); the
+certificate's slot gathers use the raw kernel's exact chunked gather.
 
 Layout of the per-config output row (float32, 128 lanes):
     [0] latency  [1] converged  [2] over-bound  [3] iters  [4] certified
@@ -46,28 +43,37 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fifo_eval.fifo_eval import (NEG, OUT_LANES,
-                                               _num_scan_steps)
+from repro.kernels.fifo_eval.fifo_eval import (LANES, OUT_LANES, VMEM_LIMIT,
+                                               _fixpoint, _gather,
+                                               _result_rows)
 
 #: default configurations per grid program.  Condensed tiles are narrow
 #: (Ec_pad is 128-512 where raw graphs run 8k-13k events), so a block of
 #: rows keeps the 8x128 vector registers busy and amortizes the per-grid
-#: step overhead; 32 is the measured sweet spot on the benchmark rungs.
+#: step overhead.
 BLOCK = 32
 
-#: VMEM working-set budget for picking a block size: ~12 live
-#: (block, Ec_pad) f32 tiles (operands + fixpoint temps) plus 4
-#: (block, V_pad) certificate tiles, kept well under the ~16 MB VMEM.
-_VMEM_BUDGET = 12 * 2**20
+#: working-set budget for picking a block size: half the scoped VMEM
+#: limit, leaving the rest to Mosaic's own temporaries
+_VMEM_BUDGET = VMEM_LIMIT // 2
+
+
+def _vmem_bytes(e_pad: int, v_pad: int, block: int) -> int:
+    """Working-set estimate of one grid program: double-buffered
+    per-config (4) and certificate (4) tiles, the times output, gather
+    scratch (3 event + 2 slot tiles), scan and certificate temporaries,
+    plus the shared (1, E) rows, each padded to an 8-sublane tile and
+    double-buffered."""
+    return 4 * block * (19 * e_pad + 14 * v_pad) + 4 * 8 * 12 * e_pad
 
 
 def pick_block(e_pad: int, v_pad: int, block: int = BLOCK) -> int:
     """Largest power-of-two block <= ``block`` whose working set fits
     the VMEM budget (never below the 8-sublane f32 min tile)."""
-    while block > 8 and (12 * e_pad + 4 * v_pad) * block * 4 > _VMEM_BUDGET:
+    while block > 8 and _vmem_bytes(e_pad, v_pad, block) > _VMEM_BUDGET:
         block //= 2
     return block
 
@@ -79,87 +85,29 @@ def _condensed_kernel(
     rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref,
     # per-config (BLOCK, V) certificate slots
     csrc_ref, cdst_ref, cthr_ref, cval_ref,
-    # outputs: result rows, then (with_times) the final event times
+    # outputs (result rows, then with_times the final event times), then
+    # scratch: three (BLOCK, E) and two (BLOCK, V) f32 tiles
     *refs,
-    e_pad: int, block: int, max_iters: int, bound: float,
-    with_times: bool,
+    max_iters: int, bound: float, with_times: bool,
 ):
     out_ref = refs[0]
-    delta = delta_ref[...]            # (1, E) f32
-    segst = segst_ref[...]            # (1, E) f32: 1.0 at segment starts
-    is_read = isread_ref[...]         # (1, E) f32 mask
-    has_data = hasdata_ref[...]       # (1, E) f32 mask
-    data_idx = didx_ref[...]          # (1, E) i32
-    end_bonus = endb_ref[...]         # (1, E) f32
-    rd_lat = rdlat_ref[...]           # (B, E) f32
-    bp_idx = bpidx_ref[...]           # (B, E) i32
-    bp_valid = bpval_ref[...]         # (B, E) f32 mask
-    bp_base = bpbase_ref[...]         # (B, E) f32
-
-    a_base = jnp.broadcast_to(jnp.where(segst > 0, NEG, delta),
-                              (block, e_pad))
-    n_steps = _num_scan_steps(e_pad)
-
-    def seg_scan(a, m):
-        # inclusive max-plus scan, Hillis-Steele doubling (static shifts)
-        for s in range(n_steps):
-            sh = 1 << s
-            a_prev = jnp.pad(a, ((0, 0), (sh, 0)),
-                             constant_values=0.0)[:, :e_pad]
-            m_prev = jnp.pad(m, ((0, 0), (sh, 0)),
-                             constant_values=NEG)[:, :e_pad]
-            m = jnp.maximum(m_prev + a, m)
-            a = a_prev + a
-        return a, m
-
-    def step(t):                      # (B, E) -> (B, E)
-        td = jnp.take(t, data_idx[0], axis=1)         # shared data edges
-        bd = jnp.where(has_data > 0, td + rd_lat, NEG)
-        tb = jnp.take_along_axis(t, bp_idx, axis=1)   # per-row bp edges
-        bb = jnp.where(bp_valid > 0, tb + bp_base, NEG)
-        b = jnp.where(is_read > 0, bd, bb)
-        m = jnp.where(segst > 0, jnp.maximum(b, delta), b)
-        A, M = seg_scan(a_base, m)
-        return jnp.maximum(A, M)
-
-    def cond(state):
-        t, it, conv, over = state
-        return jnp.any(~conv & ~over) & (it < max_iters)
-
-    def body(state):
-        # per-row freezing: finished rows (converged or past the bound)
-        # keep their times and flags while active rows step
-        t, it, conv, over = state
-        active = ~conv & ~over                        # (B,)
-        t2 = jnp.where(active[:, None], step(t), t)
-        conv = conv | (active & jnp.all(t2 == t, axis=1))
-        over = over | (active & (jnp.max(t2, axis=1) > bound))
-        return t2, it + 1, conv, over
-
-    t0 = jnp.zeros((block, e_pad), dtype=jnp.float32)
-    flags0 = jnp.zeros((block,), dtype=jnp.bool_)
-    t, iters, conv, over = lax.while_loop(
-        cond, body, body((t0, jnp.int32(0), flags0, flags0)))
+    t_ref, td_ref, tb_ref, ts_ref, tq_ref = refs[-5:]
+    iters, conv, over = _fixpoint(
+        delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref,
+        rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref, t_ref, td_ref, tb_ref,
+        max_iters=max_iters, bound=bound)
 
     # fused exactness certificate: slot v of row c is violated iff
     # valid and t[src] - t[dst] > thr (all-integer f32, exact < 2**24)
-    csrc = csrc_ref[...]              # (B, V) i32
-    cdst = cdst_ref[...]              # (B, V) i32
-    cthr = cthr_ref[...]              # (B, V) f32
-    cval = cval_ref[...]              # (B, V) f32 mask
-    ts = jnp.take_along_axis(t, csrc, axis=1)
-    td = jnp.take_along_axis(t, cdst, axis=1)
-    viol = (cval > 0) & (ts - td > cthr)
-    cert = conv & ~over & ~jnp.any(viol, axis=1)      # (B,)
+    _gather(t_ref, csrc_ref, ts_ref)
+    _gather(t_ref, cdst_ref, tq_ref)
+    viol = (cval_ref[...] > 0) & (ts_ref[...] - tq_ref[...] > cthr_ref[...])
+    any_viol = jnp.max(jnp.where(viol, 1.0, 0.0), axis=1, keepdims=True)
+    cert = jnp.where((conv > 0) & (over == 0) & (any_viol == 0), 1.0, 0.0)
 
-    latency = jnp.max(t + end_bonus, axis=1)
-    row = jnp.stack(
-        [latency,
-         conv.astype(jnp.float32),
-         over.astype(jnp.float32),
-         jnp.full((block,), iters, dtype=jnp.float32),
-         cert.astype(jnp.float32)], axis=1)           # (B, 5)
-    out_ref[...] = jnp.pad(row, ((0, 0), (0, OUT_LANES - 5)))
+    t = t_ref[...]
+    latency = jnp.max(t + endb_ref[...], axis=1, keepdims=True)
+    out_ref[...] = _result_rows(latency, conv, over, iters, cert)
     if with_times:
         refs[1][...] = t
 
@@ -170,8 +118,8 @@ def fifo_eval_condensed(
     rd_lat: jnp.ndarray, bp_idx: jnp.ndarray, bp_valid: jnp.ndarray,
     bp_base: jnp.ndarray, cert_src: jnp.ndarray, cert_dst: jnp.ndarray,
     cert_thr: jnp.ndarray, cert_valid: jnp.ndarray, *,
-    max_iters: int, bound: float, block: int = BLOCK,
-    interpret: bool = True, with_times: bool = False,
+    max_iters: int, bound: float, interpret: bool, block: int = BLOCK,
+    with_times: bool = False,
 ):
     """Launch the fused kernel.
 
@@ -180,34 +128,38 @@ def fifo_eval_condensed(
     ``block`` (the wrapper in ``kernels/fifo_eval/ops.py`` pads).
     Returns (C, OUT_LANES) f32 result rows ([4] = certificate verdict),
     plus the final (C, E) event times when ``with_times``.
+    ``interpret`` comes from
+    :func:`repro.kernels.fifo_eval.fifo_eval.kernel_interpret`.
     """
     C, e_pad = rd_lat.shape
     v_pad = cert_src.shape[1]
-    assert e_pad % 128 == 0 and v_pad % 128 == 0, \
+    assert e_pad % LANES == 0 and v_pad % LANES == 0, \
         "pad events and certificate slots to a lane multiple"
-    assert C % block == 0, "pad the config batch to a block multiple"
+    assert C % block == 0 and block % 8 == 0, \
+        "pad the config batch to a block multiple of 8 rows"
     kernel = functools.partial(
-        _condensed_kernel, e_pad=e_pad, block=block, max_iters=max_iters,
-        bound=bound, with_times=with_times)
+        _condensed_kernel, max_iters=max_iters, bound=bound,
+        with_times=with_times)
     shared = pl.BlockSpec((1, e_pad), lambda i: (0, 0))
     percfg = pl.BlockSpec((block, e_pad), lambda i: (i, 0))
     certsp = pl.BlockSpec((block, v_pad), lambda i: (i, 0))
     out_specs = [pl.BlockSpec((block, OUT_LANES), lambda i: (i, 0))]
     out_shape = [jax.ShapeDtypeStruct((C, OUT_LANES), jnp.float32)]
     if with_times:
-        out_specs.append(pl.BlockSpec((block, e_pad), lambda i: (i, 0)))
+        out_specs.append(percfg)
         out_shape.append(jax.ShapeDtypeStruct((C, e_pad), jnp.float32))
     out = pl.pallas_call(
         kernel,
         grid=(C // block,),
         in_specs=[shared] * 6 + [percfg] * 4 + [certsp] * 4,
-        out_specs=out_specs if with_times else out_specs[0],
-        out_shape=out_shape if with_times else out_shape[0],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=([pltpu.VMEM((block, e_pad), jnp.float32)] * 3
+                        + [pltpu.VMEM((block, v_pad), jnp.float32)] * 2),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
+        name="fifo_eval_condensed",
     )(delta, segst, is_read, has_data, data_idx, end_bonus,
       rd_lat, bp_idx, bp_valid, bp_base,
       cert_src, cert_dst, cert_thr, cert_valid)
-    if with_times:
-        rows, times = out
-        return rows, times
-    return out, None
+    return out[0], (out[1] if with_times else None)

@@ -8,11 +8,16 @@ indices) come from the shared :func:`~repro.core.backends.operands
 .depth_operands`; only the heavy fixpoint differs between inners:
 
 ``use_ref=False``  the Pallas kernel (:mod:`repro.kernels.fifo_eval
-                   .fifo_eval`), interpret mode on CPU
+                   .fifo_eval`): Mosaic-compiled on a TPU, interpreted
+                   on the CPU (:func:`~repro.kernels.fifo_eval.fifo_eval
+                   .kernel_interpret`)
 ``use_ref=True``   the pure-jnp oracle (:mod:`repro.kernels.fifo_eval.ref`),
                    which is also the ``fixpoint`` backend's implementation
 
 Tests diff the two against each other and against the numpy worklist.
+Each returned ``call`` carries its jitted program as ``call.run`` so a
+caller can lower one dispatch (``call.run.lower(depths)``) and inspect
+what the device runs.
 """
 
 from __future__ import annotations
@@ -33,7 +38,9 @@ from repro.core.backends.operands import (bram_count_jnp, cert_row_operands,
 from repro.core.bram import (BRAM_READ_LATENCY, SRL_BITS, SRL_DEPTH,
                              SRL_READ_LATENCY)
 from repro.core.simgraph import SimGraph
-from repro.kernels.fifo_eval.fifo_eval import fifo_eval_pallas
+from repro.kernels.fifo_eval.fifo_eval import (fifo_eval_pallas,
+                                               kernel_interpret,
+                                               kernel_platform)
 from repro.kernels.fifo_eval.ref import fifo_eval_ref, fifo_eval_ref_hetero
 
 #: device dispatches per wrapper kind ("batched" / "hetero" /
@@ -52,15 +59,14 @@ def _shard_over_rows(run: Callable, mesh) -> Callable:
     campaign mesh splits design-major row blocks onto contiguous device
     groups.  Rows are independent (one fixpoint per candidate config), so
     sharding is pure row partitioning — bit-identical to the solo path.
-    ``check_rep=False`` because ``lax.while_loop`` has no replication
-    rule; nothing here relies on replication (no collectives at all).
-    The caller must pad the row count to a multiple of the mesh size.
+    ``check_vma=False`` because nothing here relies on replication (no
+    collectives at all).  The caller must pad the row count to a
+    multiple of the mesh size.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     spec = PartitionSpec(tuple(mesh.axis_names))
-    return shard_map(run, mesh=mesh, in_specs=spec, out_specs=spec,
-                     check_rep=False)
+    return jax.shard_map(run, mesh=mesh, in_specs=spec, out_specs=spec,
+                         check_vma=False)
 
 
 def _make_run(ops, inner, max_iters: int, with_times: bool) -> Callable:
@@ -90,8 +96,7 @@ def _make_run(ops, inner, max_iters: int, with_times: bool) -> Callable:
     return run
 
 
-def make_batched_eval(ev_or_graph, interpret: bool = True,
-                      use_ref: bool = False,
+def make_batched_eval(ev_or_graph, use_ref: bool = False,
                       max_iters: int = None,
                       with_times: bool = False,
                       mesh=None) -> Callable:
@@ -117,7 +122,8 @@ def make_batched_eval(ev_or_graph, interpret: bool = True,
     ops = get_operands(g)
 
     inner = fifo_eval_ref if use_ref else functools.partial(
-        fifo_eval_pallas, interpret=interpret, with_times=with_times)
+        fifo_eval_pallas, interpret=kernel_interpret(mesh),
+        with_times=with_times)
 
     run = _make_run(ops, inner, max_iters, with_times)
     if mesh is not None:
@@ -130,11 +136,11 @@ def make_batched_eval(ev_or_graph, interpret: bool = True,
         return jax.device_get(
             run(jnp.asarray(depth_matrix, dtype=jnp.int32)))
 
+    call.run = run
     return call
 
 
-def make_condensed_eval(cg, interpret: bool = True,
-                        max_iters: int = 64,
+def make_condensed_eval(cg, max_iters: int = 64,
                         with_times: bool = False,
                         mesh=None, block: int = None
                         ) -> Optional[Callable]:
@@ -162,6 +168,7 @@ def make_condensed_eval(cg, interpret: bool = True,
     if block is None:
         block = pick_block(ops.e_pad, ct.v_pad)
     max_iters = int(max_iters)
+    interpret = kernel_interpret(mesh)
 
     def run(depths):                     # (C, F) int32, C % shards == 0
         c = depths.shape[0]
@@ -209,19 +216,27 @@ def make_condensed_eval(cg, interpret: bool = True,
         return jax.device_get(
             run(jnp.asarray(depth_matrix, dtype=jnp.int32)))
 
+    call.run = run
     return call
 
 
-def make_hetero_batched_eval(max_iters: int = 64, mesh=None) -> Callable:
+def make_hetero_batched_eval(max_iters: int = 64, mesh=None,
+                             use_ref: Optional[bool] = None) -> Callable:
     """Build the CROSS-DESIGN batched evaluation closure.
 
     Consumes the stacked per-row batch dict produced by
     :func:`repro.core.backends.operands.stack_hetero` — every row carries
-    its own (padded) event tables, so one vmapped dispatch can mix rows
-    from many SimGraphs.  The depth-dependent operand computation mirrors
+    its own (padded) event tables, so one dispatch can mix rows from many
+    SimGraphs.  The depth-dependent operand computation mirrors
     :func:`~repro.core.backends.operands.depth_operands` with per-row
     gathers (``take_along_axis`` instead of closed-over tables); the two
     are cross-validated in ``tests/test_campaign.py``.
+
+    The fixpoint runs in the raw Pallas kernel with per-row tables where
+    the kernels compile (a TPU) and in the vmapped jnp reference where
+    they would be interpreted (the CPU); ``use_ref`` forces one or the
+    other.  The reference's per-row gathers inside a vmapped while loop
+    take the TPU compiler minutes per batch shape, the kernel seconds.
 
     Returns ``call(batch) -> (latency i64, bram i64, status i8)``; the
     jit cache is keyed on the batch shape, so callers should bucket the
@@ -234,6 +249,9 @@ def make_hetero_batched_eval(max_iters: int = 64, mesh=None) -> Callable:
     mesh contiguous design blocks land on contiguous device groups.  The
     (bucketed) row count must be a multiple of the mesh size.
     """
+    if use_ref is None:
+        use_ref = kernel_platform(mesh) != "tpu"
+    interpret = False if use_ref else kernel_interpret(mesh)
 
     def run(b):
         d = b["depths"].astype(jnp.int32)              # (C, F*)
@@ -254,10 +272,16 @@ def make_hetero_batched_eval(max_iters: int = 64, mesh=None) -> Callable:
                         b["n_flat_reads"][:, None] - 1)
         bp_idx = jnp.take_along_axis(
             b["read_evt_flat"].astype(jnp.int32), flat, axis=1)
-        out = fifo_eval_ref_hetero(
-            b["delta"], b["seg_start"], b["is_read"], b["has_data"],
-            b["data_idx"].astype(jnp.int32), b["end_bonus"],
-            rd_lat_e, bp_idx, bp_valid, b["bound"], max_iters=max_iters)
+        tables = (b["delta"], b["seg_start"], b["is_read"], b["has_data"],
+                  b["data_idx"].astype(jnp.int32), b["end_bonus"],
+                  rd_lat_e, bp_idx, bp_valid)
+        if use_ref:
+            out = fifo_eval_ref_hetero(*tables, b["bound"],
+                                       max_iters=max_iters)
+        else:
+            bp_base = jnp.ones((1, rd_lat_e.shape[1]), jnp.float32)
+            out, _ = fifo_eval_pallas(*tables, bp_base, max_iters=max_iters,
+                                      bound=b["bound"], interpret=interpret)
         lat = jnp.maximum(out[:, 0], b["taskless"])
         conv = out[:, 1] > 0
         over = out[:, 2] > 0
@@ -278,4 +302,5 @@ def make_hetero_batched_eval(max_iters: int = 64, mesh=None) -> Callable:
         lat = np.asarray(np.rint(lat), dtype=np.int64)
         return lat, np.asarray(bram, dtype=np.int64), np.asarray(status)
 
+    call.run = run
     return call

@@ -58,11 +58,9 @@ def ensure_host_platform_devices(n: int) -> bool:
     if "xla_force_host_platform_device_count" in flags:
         return True
     if "jax" in sys.modules:
-        try:
-            from jax._src import xla_bridge
-            if xla_bridge.backends_are_initialized():
-                return False
-        except Exception:          # private API moved: assume too late
+        # jax 0.9.0 (the pinned version) has no public form of this check
+        from jax._src import xla_bridge
+        if xla_bridge.backends_are_initialized():
             return False
     os.environ["XLA_FLAGS"] = (
         f"{flags} --xla_force_host_platform_device_count={int(n)}".strip())

@@ -14,8 +14,9 @@ host ground truth at the bit level:
   through the cascade as forcing the safe rung / raw backstop directly,
 * a fully-certifying batch is device-resident: exactly one dispatch and
   the host verifier provably never runs,
-* everything runs under ``interpret=True`` (no TPU in CI); the interpret
-  flag is parametrized so real hardware can exercise ``False``.
+* the interpret flag follows the platform (``interpret_for``): the
+  kernel is interpreted here on the CPU and compiled on a TPU, where
+  ``chip_smoke.py`` runs the same identities.
 
 Integer-exactness makes every assertion ``assert_array_equal`` — never
 allclose.
@@ -42,6 +43,7 @@ from repro.core.simulate import BatchedEvaluator
 from repro.designs import make_design, mult_by_2
 from repro.designs.generate import (DesignSpec, build_design,
                                     generate_design)
+from repro.kernels.fifo_eval.fifo_eval import interpret_for, kernel_interpret
 from repro.kernels.fifo_eval.ops import (DISPATCH_COUNTS,
                                          make_condensed_eval)
 
@@ -71,13 +73,12 @@ def _hot_rows(g, C, seed=0):
         for _ in range(C)]).astype(np.int32)
 
 
-def _assert_kernel_cert_matches_verify_rows(g, rows, interpret=True):
+def _assert_kernel_cert_matches_verify_rows(g, rows):
     """For every rung with expressible certificate tables: the kernel's
     on-device mask == CONVERGED & host ``verify_rows``, bit for bit."""
     n_checked = 0
     for cg in condense_auto(g):
-        fused = make_condensed_eval(cg, interpret=interpret,
-                                    max_iters=64, with_times=True)
+        fused = make_condensed_eval(cg, max_iters=64, with_times=True)
         if fused is None:
             continue                  # no cert tables -> host verifier
         lat, bram, status, cert, times = (np.asarray(x)
@@ -216,16 +217,24 @@ def test_fully_certifying_batch_is_device_resident(monkeypatch):
 
 
 # ------------------------------------------------- interpret flag
-@pytest.mark.parametrize("interpret", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        jax.default_backend() == "cpu",
-        reason="interpret=False needs a real TPU/accelerator")),
-])
+@pytest.mark.parametrize("interpret", [True, False])
 def test_kernel_runs_under_interpret_flag(interpret):
-    """The kernel is validated in interpret mode on CPU (the CI
-    environment has no TPU); on real hardware the same test body runs
-    compiled.  docs/performance.md documents the flag."""
+    """The kernel identity on the platform whose kernels run with this
+    flag: interpreted on the CPU, compiled on a TPU (where
+    ``chip_smoke.py`` also runs it; ``tests/test_tpu_compile.py``
+    compiles it for a described chip)."""
+    platform = "cpu" if interpret else "tpu"
+    if jax.devices()[0].platform != platform:
+        pytest.skip(f"runs on a {platform} device")
+    assert kernel_interpret() is interpret
     g = build_simgraph(make_design("gemm"))
-    _assert_kernel_cert_matches_verify_rows(
-        g, _probe_rows(g, n_random=3), interpret=interpret)
+    _assert_kernel_cert_matches_verify_rows(g, _probe_rows(g, n_random=3))
+
+
+def test_interpret_rule_follows_platform():
+    """Interpreted on the CPU, compiled on a TPU, and no kernel path is
+    guessed for any other platform."""
+    assert interpret_for("cpu") is True
+    assert interpret_for("tpu") is False
+    with pytest.raises(ValueError, match="gpu"):
+        interpret_for("gpu")

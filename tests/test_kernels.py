@@ -1,4 +1,4 @@
-"""Pallas kernel validation: interpret-mode kernel vs pure-jnp ref vs the
+"""Pallas kernel validation: the kernel (interpreted on CPU) vs pure-jnp ref vs the
 numpy worklist, swept over designs (event counts straddling the 128-lane
 padding boundary), batch sizes, and FIFO widths (which flip the SRL/BRAM
 read-latency path).  Results are integer-exact, so equality — not
@@ -47,7 +47,7 @@ def test_kernel_matches_ref_and_worklist(name, factory, batch):
                      for _ in range(max(batch - 2, 0))])[:batch]
 
     ev = BatchedEvaluator(g, EvalConfig(backend="numpy", max_iters=64))
-    pallas_call = make_batched_eval(ev, interpret=True, max_iters=128)
+    pallas_call = make_batched_eval(ev, max_iters=128)
     ref_call = make_batched_eval(ev, use_ref=True, max_iters=128)
 
     lat_p, bram_p, st_p = pallas_call(cfgs)
@@ -129,7 +129,7 @@ def test_kernel_iteration_cap_reports_unresolved_not_wrong():
     d = mult_by_2(32)
     g = build_simgraph(d)
     ev = BatchedEvaluator(g, EvalConfig(backend="numpy", max_iters=64))
-    call = make_batched_eval(ev, interpret=True, max_iters=2)
+    call = make_batched_eval(ev, max_iters=2)
     cfgs = np.array([[40, 2], [2, 2]])
     lat, _, st = call(cfgs)
     for i in range(2):

@@ -18,6 +18,7 @@ anyway.
 import glob
 import json
 import os
+from pathlib import Path
 
 import numpy as np
 import pytest
@@ -263,36 +264,53 @@ def test_spawn_preserves_mesh_and_calibration_lists_mesh():
                              key=ev.calibration["probe_s"].get)
 
 
-def test_jit_cache_env_unset_is_inert(monkeypatch):
-    """Without REPRO_JIT_CACHE_DIR, configure_jax touches nothing (and
-    never imports jax on its own)."""
-    from repro.core.backends import jaxcfg
-    monkeypatch.delenv(jaxcfg.ENV_VAR, raising=False)
-    assert jaxcfg.configure_jax(force=True) is False
-
-
-def test_jit_cache_env_populates_cache_dir(tmp_path):
-    """REPRO_JIT_CACHE_DIR=dir makes the first backend jit write
-    persistent cache entries into dir.  Runs in a subprocess because
-    jax's compilation cache binds its directory at the process's first
-    compile — exactly the wiring (operands imports -> configure_jax)
-    this guards."""
+def _first_jit_in_child(env):
+    """Run one backend jit in a fresh process (jax binds its cache
+    directory at the process's first compile — exactly the wiring,
+    operands import -> configure_jax, these tests guard); returns the
+    cache directory the child's jax config names."""
     import subprocess
     import sys
-    from repro.core.backends import jaxcfg
-    cache_dir = tmp_path / "jitcache"
-    env = dict(os.environ, **{jaxcfg.ENV_VAR: str(cache_dir)})
-    env["PYTHONPATH"] = os.pathsep.join(sys.path)
+    env = dict(env, PYTHONPATH=os.pathsep.join(sys.path), JAX_PLATFORMS="cpu")
     code = (
-        "import numpy as np\n"
+        "import jax, numpy as np\n"
         "from repro.core import EvalConfig, build_simgraph\n"
         "from repro.core.simulate import BatchedEvaluator\n"
         "from repro.designs.ddcf import mult_by_2\n"
         "g = build_simgraph(mult_by_2(8))\n"
         "ev = BatchedEvaluator(g, EvalConfig(backend='jax', max_iters=64))\n"
-        "ev.evaluate(np.stack([g.upper_bounds] * 2))\n")
-    subprocess.run([sys.executable, "-c", code], env=env, check=True,
-                   capture_output=True, text=True)
-    assert os.path.isdir(cache_dir)
-    assert any("cache" in name for name in os.listdir(cache_dir)), \
+        "ev.evaluate(np.stack([g.upper_bounds] * 2))\n"
+        "print(jax.config.jax_compilation_cache_dir)\n")
+    out = subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                         capture_output=True, text=True)
+    return out.stdout.strip().splitlines()[-1]
+
+
+def _has_entries(path):
+    return os.path.isdir(path) and any("cache" in name
+                                       for name in os.listdir(path))
+
+
+def test_jit_cache_env_unset_is_inert():
+    """Without JAX_COMPILATION_CACHE_DIR the cache stays inert outside
+    the checkout: it goes to the fixed in-checkout directory
+    ``<repo>/.jax_cache`` (never a temp, PID or time-derived path, and
+    git-ignored), and the first backend jit writes entries there."""
+    from repro.core.backends import jaxcfg
+    env = {k: v for k, v in os.environ.items() if k != jaxcfg.ENV_VAR}
+    assert jaxcfg.DEFAULT_DIR.parent == Path(__file__).resolve().parents[1]
+    assert _first_jit_in_child(env) == str(jaxcfg.DEFAULT_DIR)
+    assert _has_entries(jaxcfg.DEFAULT_DIR), \
+        "backend jit wrote no persistent cache entries"
+
+
+def test_jit_cache_env_populates_cache_dir(tmp_path):
+    """JAX_COMPILATION_CACHE_DIR=dir is left to jax: the first backend
+    jit writes its persistent cache entries into dir, and no other
+    directory is set in code."""
+    from repro.core.backends import jaxcfg
+    cache_dir = tmp_path / "jitcache"
+    env = dict(os.environ, **{jaxcfg.ENV_VAR: str(cache_dir)})
+    assert _first_jit_in_child(env) == str(cache_dir)
+    assert _has_entries(cache_dir), \
         "backend jit wrote no persistent cache entries"
