@@ -1,0 +1,3 @@
+"""Device idle share of the traced window in k15mmtree_relu.random."""
+
+from bench.readers import idle_share as read  # noqa: F401
