@@ -1,4 +1,4 @@
-"""Benchmark harness: one module per paper table/figure + roofline.
+"""Benchmark harness: one module per paper table/figure.
 
   accuracy.py      Table II   (trace-sim vs cycle-accurate oracle)
   pareto_fronts.py Fig. 3     (frontiers on selected designs)
@@ -8,7 +8,6 @@
   case_study.py    Fig. 6     (FlowGNN-PNA DDCF case study)
   batched_eval.py  beyond-paper evaluator throughput
   pruning.py       beyond-paper sound lower-bound pruning
-  roofline.py      dry-run roofline aggregation (EXPERIMENTS.md §Roofline)
 
 Run everything: PYTHONPATH=src python -m benchmarks.run   (FULL=1 for the
 full-budget versions used in EXPERIMENTS.md).

@@ -140,15 +140,6 @@ def _pruning() -> str:
             f"{k['random_pruned']['dead']}")
 
 
-def _roofline() -> str:
-    from benchmarks import roofline
-    rows = roofline.load()
-    if not rows:
-        return "no_dryrun_records"
-    rep = roofline.pick_hillclimb_cells(rows)["paper_representative"]
-    return f"cells={len(rows)};rep={rep['arch']}x{rep['shape']}"
-
-
 #: canonical order — ``--only`` subsets preserve it
 STEPS = [
     ("accuracy", _accuracy),
@@ -168,7 +159,6 @@ STEPS = [
     ("bounds", _bounds),
     ("chaos", _chaos),
     ("pruning", _pruning),
-    ("roofline", _roofline),
 ]
 
 

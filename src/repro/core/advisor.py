@@ -22,6 +22,7 @@ from repro.core.optimizers import OPTIMIZERS, EvalContext, OptResult
 from repro.core.pareto import hypervolume_2d, select_alpha_point
 from repro.core.simgraph import SimGraph, build_simgraph
 from repro.core.simulate import BatchedEvaluator
+from repro.core.spans import span
 from repro.core.tracer import Trace, collect_trace
 
 
@@ -362,9 +363,10 @@ class FifoAdvisor:
         constructor.  Repeated runs share this advisor's cache.
         """
         cls = OPTIMIZERS[optimizer]
-        ctx = self._fresh_ctx(seed)
-        opt = cls(ctx, budget=budget, **kwargs)
-        res = opt.run()
+        with span("search"):
+            ctx = self._fresh_ctx(seed)
+            opt = cls(ctx, budget=budget, **kwargs)
+            res = opt.run()
         return DseResult(design_name=self.design.name, optimizer=optimizer,
                          result=res, baseline_max=self.baseline_max,
                          baseline_min=self.baseline_min,

@@ -21,6 +21,8 @@ from typing import Dict, Tuple
 
 import numpy as np
 
+from repro.core.spans import span
+
 _HASH_SEED = 0x9E3779B97F4A7C15
 
 
@@ -92,45 +94,46 @@ class ConfigCache:
         Hit rows are filled from the cache; rows flagged in ``miss_mask``
         must be evaluated and then recorded via :meth:`insert`.
         """
-        m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int64))
-        C = m.shape[0]
-        lat = np.zeros(C, dtype=np.int64)
-        bram = np.zeros(C, dtype=np.int64)
-        dead = np.zeros(C, dtype=bool)
-        miss = np.ones(C, dtype=bool)
-        if self._n:
-            hashes = self._hash_rows(m)
-            # vectorized hit resolution: one searchsorted over the lazily
-            # maintained sorted hash index replaces the per-row dict loop
-            # (the stable sort keeps the first-inserted entry first, so a
-            # duplicate hash resolves to the same winner the insert-time
-            # dict keeps)
-            sh, sidx = self._index()
-            if sh.size:
-                pos = np.minimum(np.searchsorted(sh, hashes), sh.size - 1)
-                idx = np.where(sh[pos] == hashes, sidx[pos], -1)
-            else:
-                idx = np.full(C, -1, dtype=np.int64)
-            if self._tail_start < self._n:
-                # entries inserted since the last index rebuild: resolve
-                # the (few) rows the sorted part missed through the dict
-                for i in np.flatnonzero(idx < 0):
-                    idx[i] = self._map.get(int(hashes[i]), -1)
-            cand = np.flatnonzero(idx >= 0)
-            if cand.size:
-                # exact verification: collisions fall back to miss
-                ok = (self._rows[idx[cand]] == m[cand]).all(axis=1)
-                self.stats.collisions += int((~ok).sum())
-                hit_rows = cand[ok]
-                src = idx[hit_rows]
-                lat[hit_rows] = self._lat[src]
-                bram[hit_rows] = self._bram[src]
-                dead[hit_rows] = self._dead[src]
-                miss[hit_rows] = False
-        n_miss = int(miss.sum())
-        self.stats.misses += n_miss
-        self.stats.hits += C - n_miss
-        return lat, bram, dead, miss
+        with span("cache"):
+            m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int64))
+            C = m.shape[0]
+            lat = np.zeros(C, dtype=np.int64)
+            bram = np.zeros(C, dtype=np.int64)
+            dead = np.zeros(C, dtype=bool)
+            miss = np.ones(C, dtype=bool)
+            if self._n:
+                hashes = self._hash_rows(m)
+                # vectorized hit resolution: one searchsorted over the lazily
+                # maintained sorted hash index replaces the per-row dict loop
+                # (the stable sort keeps the first-inserted entry first, so a
+                # duplicate hash resolves to the same winner the insert-time
+                # dict keeps)
+                sh, sidx = self._index()
+                if sh.size:
+                    pos = np.minimum(np.searchsorted(sh, hashes), sh.size - 1)
+                    idx = np.where(sh[pos] == hashes, sidx[pos], -1)
+                else:
+                    idx = np.full(C, -1, dtype=np.int64)
+                if self._tail_start < self._n:
+                    # entries inserted since the last index rebuild: resolve
+                    # the (few) rows the sorted part missed through the dict
+                    for i in np.flatnonzero(idx < 0):
+                        idx[i] = self._map.get(int(hashes[i]), -1)
+                cand = np.flatnonzero(idx >= 0)
+                if cand.size:
+                    # exact verification: collisions fall back to miss
+                    ok = (self._rows[idx[cand]] == m[cand]).all(axis=1)
+                    self.stats.collisions += int((~ok).sum())
+                    hit_rows = cand[ok]
+                    src = idx[hit_rows]
+                    lat[hit_rows] = self._lat[src]
+                    bram[hit_rows] = self._bram[src]
+                    dead[hit_rows] = self._dead[src]
+                    miss[hit_rows] = False
+            n_miss = int(miss.sum())
+            self.stats.misses += n_miss
+            self.stats.hits += C - n_miss
+            return lat, bram, dead, miss
 
     def _index(self):
         """The sorted hash index, rebuilt lazily and AMORTIZED: a rebuild
@@ -199,21 +202,22 @@ class ConfigCache:
     def insert(self, depth_matrix: np.ndarray, lat: np.ndarray,
                bram: np.ndarray, dead: np.ndarray):
         """Record evaluated rows (duplicates of cached rows are skipped)."""
-        m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int64))
-        C = m.shape[0]
-        self._grow_to(self._n + C)
-        hashes = self._hash_rows(m)
-        for i in range(C):
-            h = int(hashes[i])
-            j = self._map.get(h)
-            if j is not None:
-                # already present (or a collision slot: keep first winner)
-                continue
-            j = self._n
-            self._rows[j] = m[i]
-            self._lat[j] = lat[i]
-            self._bram[j] = bram[i]
-            self._dead[j] = dead[i]
-            self._hashes[j] = hashes[i]
-            self._map[h] = j
-            self._n += 1
+        with span("cache"):
+            m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int64))
+            C = m.shape[0]
+            self._grow_to(self._n + C)
+            hashes = self._hash_rows(m)
+            for i in range(C):
+                h = int(hashes[i])
+                j = self._map.get(h)
+                if j is not None:
+                    # already present (or a collision slot: keep first winner)
+                    continue
+                j = self._n
+                self._rows[j] = m[i]
+                self._lat[j] = lat[i]
+                self._bram[j] = bram[i]
+                self._dead[j] = dead[i]
+                self._hashes[j] = hashes[i]
+                self._map[h] = j
+                self._n += 1

@@ -13,7 +13,14 @@ Owns the three batch-shaping concerns that used to be tangled into
 3. **Escalation** — UNRESOLVED rows (the iteration cap fired before the
    fixpoint converged: deadlocks never converge by construction, and rare
    feasible rows converge slowly) are re-solved exactly by the worklist
-   arbiter, counted in ``stats.n_fallbacks``.
+   arbiter, counted in ``stats.n_fallbacks``, timed in
+   ``stats.worklist_s``.
+
+Each layer opens a ``fifo.<name>`` profiler span (:mod:`repro.core.spans`)
+once per call: ``raw``, ``worklist``, ``rung.<tag>``, and the
+cross-design phases ``hetero.stack``, ``.pad``, ``.h2d``, ``.wait`` and
+``.scatter``.  A raw-kernel launch's iteration lane is counted into
+``stats.raw_rows``, ``raw_row_iters`` and ``raw_tile_iters``.
 
 :class:`RungCascade` owns the condensation escalation ladder (moved here
 from ``BatchedEvaluator``): route each row through the most aggressive
@@ -42,8 +49,20 @@ from repro.core.backends.base import (CONVERGED, DEADLOCK, F32_EXACT_LIMIT,
                                       EvalBackend, UNRESOLVED)
 from repro.core.backends.worklist import WorklistBackend
 from repro.core.simgraph import SimGraph
+from repro.core.spans import span
 
 BUCKETS = (1, 8, 32, 128, 512, 2048)
+
+
+def _count_iters(stats, backend: EvalBackend, rows: int) -> None:
+    """Add the iteration lane of ``backend``'s last raw-kernel launch,
+    whose first ``rows`` rows are real, to ``stats``."""
+    iters = getattr(backend, "last_iters", None)
+    if stats is None or iters is None:
+        return
+    stats.raw_rows += rows
+    stats.raw_row_iters += int(iters[:rows].sum())
+    stats.raw_tile_iters += backend.last_tile_iters
 
 
 class DispatchPolicy:
@@ -84,13 +103,16 @@ class DispatchPolicy:
         m = np.atleast_2d(np.asarray(depth_matrix))
         C = m.shape[0]
         batch = self.pad_batch(m) if backend.wants_bucketing else m
-        lat, bram, status = backend.evaluate(batch)
+        with span("raw"):
+            lat, bram, status = backend.evaluate(batch)
+        _count_iters(stats, backend, C)
         lat, bram, status = lat[:C], bram[:C], status[:C]
 
         dead = status == DEADLOCK
         unresolved = np.flatnonzero(status == UNRESOLVED)
         if unresolved.size:
-            wl_lat, _, wl_status = self.worklist.evaluate(m[unresolved])
+            with span("worklist", stats, "worklist_s"):
+                wl_lat, _, wl_status = self.worklist.evaluate(m[unresolved])
             lat[unresolved] = wl_lat
             dead[unresolved] = wl_status == DEADLOCK
             if stats is not None:
@@ -155,23 +177,25 @@ class RungCascade:
                 batch = self.policy.pad_batch(rows)
             else:
                 batch = rows
-            if fused:
-                rlat, _, rstatus, ok = impl.evaluate_certified(batch)
-                rlat = rlat[: sel.size]
-                rstatus = rstatus[: sel.size]
-                ok = ok[: sel.size]
-                dl = rstatus == DEADLOCK   # sound: relaxed system stalls
-            else:
-                rlat, _, rstatus, times = impl.evaluate_with_times(batch)
-                rlat = rlat[: sel.size]
-                rstatus = rstatus[: sel.size]
-                times = times[: sel.size, : cg.n_events]
-                dl = rstatus == DEADLOCK
-                ok = np.zeros(sel.size, dtype=bool)
-                conv = rstatus == CONVERGED
-                if conv.any():
-                    ci = np.flatnonzero(conv)
-                    ok[ci] = verify_rows(cg, rows[ci], times[ci])
+            with span("rung." + cg.tag):
+                if fused:
+                    rlat, _, rstatus, ok = impl.evaluate_certified(batch)
+                    rlat = rlat[: sel.size]
+                    rstatus = rstatus[: sel.size]
+                    ok = ok[: sel.size]
+                    dl = rstatus == DEADLOCK   # sound: relaxed system stalls
+                else:
+                    rlat, _, rstatus, times = impl.evaluate_with_times(batch)
+                    _count_iters(stats, impl, sel.size)
+                    rlat = rlat[: sel.size]
+                    rstatus = rstatus[: sel.size]
+                    times = times[: sel.size, : cg.n_events]
+                    dl = rstatus == DEADLOCK
+                    ok = np.zeros(sel.size, dtype=bool)
+                    conv = rstatus == CONVERGED
+                    if conv.any():
+                        ci = np.flatnonzero(conv)
+                        ok[ci] = verify_rows(cg, rows[ci], times[ci])
             acc = dl | ok
             if stats is not None:
                 stats.n_cond_fail += int(sel.size - acc.sum())
@@ -200,6 +224,8 @@ class HeteroStats:
     n_pad_rows: int = 0      # bucket-padding overhead rows
     n_fallbacks: int = 0     # UNRESOLVED rows escalated to a worklist
     wall_s: float = 0.0
+    prep_s: float = 0.0      # stack, pad and send, until the launch is queued
+    wait_s: float = 0.0      # from the launch until results are on the host
 
 
 class HeteroDispatcher:
@@ -242,9 +268,11 @@ class HeteroDispatcher:
         self.mesh = mesh
         self.shard_multiple = (int(mesh.devices.size)
                                if mesh is not None else 1)
-        self._call = make_hetero_batched_eval(max_iters, mesh=mesh)
-        self.buckets = tuple(buckets)
         self.stats = HeteroStats()
+        # the call adds its send to stats.prep_s and its wait to wait_s
+        self._call = make_hetero_batched_eval(max_iters, mesh=mesh,
+                                              stats=self.stats)
+        self.buckets = tuple(buckets)
         worklists = worklists or {}
         if graphs:
             # pre-compute the shared envelope so registering N designs
@@ -321,30 +349,34 @@ class HeteroDispatcher:
         t_start = time.perf_counter()
         mats = [np.atleast_2d(np.asarray(m, dtype=np.int64))
                 for _, m in items]
-        batch = stack_hetero(
-            [(self._ext[k], m) for (k, _), m in zip(items, mats)])
+        with span("hetero.stack", self.stats, "prep_s"):
+            batch = stack_hetero(
+                [(self._ext[k], m) for (k, _), m in zip(items, mats)])
         C = batch["depths"].shape[0]
-        padded, c_padded = self._pad_rows(batch, C)
+        with span("hetero.pad", self.stats, "prep_s"):
+            padded, c_padded = self._pad_rows(batch, C)
         lat, bram, status = self._call(padded)
         lat, bram, status = lat[:C], bram[:C], status[:C]
 
         out = []
         row0 = 0
-        for (key, _), m in zip(items, mats):
-            c = m.shape[0]
-            sl = slice(row0, row0 + c)
-            row0 += c
-            lat_i, bram_i = lat[sl].copy(), bram[sl].copy()
-            dead_i = status[sl] == DEADLOCK
-            unresolved = np.flatnonzero(status[sl] == UNRESOLVED)
-            if unresolved.size:
-                wl_lat, _, wl_status = self.worklists[key].evaluate(
-                    m[unresolved])
-                lat_i[unresolved] = wl_lat
-                dead_i[unresolved] = wl_status == DEADLOCK
-                self.stats.n_fallbacks += int(unresolved.size)
-            lat_i = np.where(dead_i, -1, lat_i)
-            out.append((lat_i, bram_i, dead_i))
+        with span("hetero.scatter"):
+            for (key, _), m in zip(items, mats):
+                c = m.shape[0]
+                sl = slice(row0, row0 + c)
+                row0 += c
+                lat_i, bram_i = lat[sl].copy(), bram[sl].copy()
+                dead_i = status[sl] == DEADLOCK
+                unresolved = np.flatnonzero(status[sl] == UNRESOLVED)
+                if unresolved.size:
+                    with span("worklist"):
+                        wl_lat, _, wl_status = self.worklists[key].evaluate(
+                            m[unresolved])
+                    lat_i[unresolved] = wl_lat
+                    dead_i[unresolved] = wl_status == DEADLOCK
+                    self.stats.n_fallbacks += int(unresolved.size)
+                lat_i = np.where(dead_i, -1, lat_i)
+                out.append((lat_i, bram_i, dead_i))
         self.stats.n_dispatches += 1
         self.stats.n_rows += C
         self.stats.n_pad_rows += c_padded - C

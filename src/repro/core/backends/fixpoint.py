@@ -53,6 +53,12 @@ class _ScanBackend(EvalBackend):
     #: a jax.sharding.Mesh to shard the config-row axis over (None = solo
     #: jit on the default device); set by the MeshBackend subclass
     mesh = None
+    #: the Pallas raw kernel's iteration lane for each row of the last
+    #: ``evaluate`` / ``evaluate_with_times`` batch, and that launch's
+    #: vreg-tile iterations (see :meth:`_note_iters`); None on the jnp
+    #: reference, which runs no kernel blocks
+    last_iters = None
+    last_tile_iters = 0
 
     @property
     def shard_multiple(self) -> int:
@@ -116,11 +122,26 @@ class _ScanBackend(EvalBackend):
         return (lat, bram, np.asarray(status[:c], dtype=np.int8),
                 np.asarray(cert[:c], dtype=bool))
 
+    def _note_iters(self, iters: np.ndarray, c: int) -> None:
+        """Keep lane 3 of a raw-kernel launch: every row of an 8-row
+        block carries the block's Jacobi iterations, and each iteration
+        of a block steps one f32 vreg tile per 128 events."""
+        if self.use_ref:
+            return
+        from repro.kernels.fifo_eval.fifo_eval import LANES, ROWS
+        iters = np.asarray(iters, dtype=np.int64)
+        self.last_iters = iters[:c]
+        # under a mesh each device blocks its own row shard
+        blocks = sum(int(s[::ROWS].sum())
+                     for s in np.split(iters, self.shard_multiple))
+        self.last_tile_iters = blocks * (self.ops.e_pad // LANES)
+
     def evaluate(self, depth_matrix: np.ndarray
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int32))
         m, c = self._pad_shards(m)
-        lat, bram, status = self._call(m)
+        lat, bram, status, iters = self._call(m)
+        self._note_iters(iters, c)
         lat = np.asarray(np.rint(lat[:c]), dtype=np.int64)
         bram = np.asarray(bram[:c], dtype=np.int64)
         return lat, bram, np.asarray(status[:c], dtype=np.int8)
@@ -137,7 +158,8 @@ class _ScanBackend(EvalBackend):
                 with_times=True, mesh=self.mesh)
         m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int32))
         m, c = self._pad_shards(m)
-        lat, bram, status, times = self._call_times(m)
+        lat, bram, status, iters, times = self._call_times(m)
+        self._note_iters(iters, c)
         lat = np.asarray(np.rint(lat[:c]), dtype=np.int64)
         bram = np.asarray(bram[:c], dtype=np.int64)
         times = np.asarray(np.rint(times[:c]), dtype=np.int64)

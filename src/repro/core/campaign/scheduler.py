@@ -40,6 +40,7 @@ from repro.core.campaign.router import RoundRouter, RoutedRequest
 from repro.core.config import EvalConfig
 from repro.core.optimizers import OPTIMIZERS, OptResult
 from repro.core.pareto import hypervolume_2d
+from repro.core.spans import span
 from repro.designs import QUICK_DESIGNS, make_design
 
 __all__ = ["Campaign", "CampaignSpec", "CampaignTask", "DesignContext",
@@ -268,37 +269,38 @@ class Campaign:
     # ------------------------------------------------------------- rounds
     def _round(self) -> int:
         """Advance every active task one step; returns #active tasks."""
-        pending: List[RoutedRequest] = []
-        for task in self.tasks:
-            if task.done:
-                continue
-            req = task.opt.propose()
-            if req is None:
-                task.finalize()
-                continue
-            lat, bram, dead, miss = task.dctx.cache.lookup(req.depths)
-            pending.append(RoutedRequest(
-                key=task.spec.design, req=req, lat=lat, bram=bram,
-                dead=dead, miss_rows=np.flatnonzero(miss),
-                lane=task.worker, tag=task))
-        self.router.route(pending)
-        for p in pending:
-            task = p.tag
-            rows = p.miss_rows
-            if rows.size:
-                task.dctx.cache.insert(
-                    p.req.depths[rows], p.lat[rows], p.bram[rows],
-                    p.dead[rows])
-            task.eval_s += p.eval_s
-            task.ctx.record(p.req.depths, p.lat, p.bram, p.dead,
-                            rows.size)
-            task.step_miss.append(int(rows.size))
-            task.opt.observe(p.lat, p.bram, p.dead)
-            if self.spec.track_hypervolume:
-                task.hv_trace.append(
-                    (task.ctx.n_evals, task.running_hypervolume()))
-        self.round += 1
-        return len(pending)
+        with span("campaign.round"):
+            pending: List[RoutedRequest] = []
+            for task in self.tasks:
+                if task.done:
+                    continue
+                req = task.opt.propose()
+                if req is None:
+                    task.finalize()
+                    continue
+                lat, bram, dead, miss = task.dctx.cache.lookup(req.depths)
+                pending.append(RoutedRequest(
+                    key=task.spec.design, req=req, lat=lat, bram=bram,
+                    dead=dead, miss_rows=np.flatnonzero(miss),
+                    lane=task.worker, tag=task))
+            self.router.route(pending)
+            for p in pending:
+                task = p.tag
+                rows = p.miss_rows
+                if rows.size:
+                    task.dctx.cache.insert(
+                        p.req.depths[rows], p.lat[rows], p.bram[rows],
+                        p.dead[rows])
+                task.eval_s += p.eval_s
+                task.ctx.record(p.req.depths, p.lat, p.bram, p.dead,
+                                rows.size)
+                task.step_miss.append(int(rows.size))
+                task.opt.observe(p.lat, p.bram, p.dead)
+                if self.spec.track_hypervolume:
+                    task.hv_trace.append(
+                        (task.ctx.n_evals, task.running_hypervolume()))
+            self.round += 1
+            return len(pending)
 
     # -------------------------------------------------------------- runs
     def run(self, max_rounds: Optional[int] = None):

@@ -33,6 +33,7 @@ from repro.core.bram import breakpoints
 from repro.core.pareto import pareto_front
 from repro.core.simgraph import SimGraph
 from repro.core.simulate import BatchedEvaluator
+from repro.core.spans import span
 
 
 @dataclasses.dataclass
@@ -343,17 +344,15 @@ class Optimizer:
             self._advance(None)
 
     def _advance(self, results) -> None:
-        t0 = time.perf_counter()
-        try:
-            if results is None:
-                self._pending = next(self._gen)
-            else:
-                self._pending = self._gen.send(results)
-        except StopIteration:
-            self._pending = None
-            self._done = True
-        finally:
-            self.step_s += time.perf_counter() - t0
+        with span("optimizer", self, "step_s"):
+            try:
+                if results is None:
+                    self._pending = next(self._gen)
+                else:
+                    self._pending = self._gen.send(results)
+            except StopIteration:
+                self._pending = None
+                self._done = True
 
     def propose(self) -> Optional[EvalRequest]:
         """The outstanding batch to evaluate; None once the search ended."""

@@ -46,6 +46,7 @@ from repro.core.backends.worklist import WorklistState
 from repro.core.bram import design_bram_np
 from repro.core.config import EvalConfig, resolve_config
 from repro.core.simgraph import SimGraph
+from repro.core.spans import span
 
 __all__ = [
     "BIG", "CONVERGED", "DEADLOCK", "F32_EXACT_LIMIT", "UNRESOLVED",
@@ -71,6 +72,10 @@ class BatchStats:
     n_condensed: int = 0      # rows resolved on a condensed rung
     n_cond_fail: int = 0      # rung attempts whose certificate failed
     wall_s: float = 0.0
+    worklist_s: float = 0.0   # escalating UNRESOLVED rows to the worklist
+    raw_rows: int = 0         # real rows of fifo_eval_raw launches
+    raw_row_iters: int = 0    # their Jacobi iterations (the kernel's lane 3)
+    raw_tile_iters: int = 0   # 8-row block iterations x E_pad / 128 vregs
 
 
 #: historical BatchedEvaluator default (the advisor default is 256)
@@ -267,14 +272,16 @@ class BatchedEvaluator:
         depth_matrix = np.atleast_2d(np.asarray(depth_matrix))
         t_start = time.perf_counter()
         C = depth_matrix.shape[0]
-        uniq, inverse = np.unique(depth_matrix, axis=0,
-                                  return_inverse=True)
-        if uniq.shape[0] < C:
-            lat, bram, dead = self._eval_rows(uniq)
-            lat, bram, dead = lat[inverse], bram[inverse], dead[inverse]
-            self.stats.n_dedup += C - uniq.shape[0]
-        else:
-            lat, bram, dead = self._eval_rows(depth_matrix)
+        with span("evaluate"):
+            uniq, inverse = np.unique(depth_matrix, axis=0,
+                                      return_inverse=True)
+            if uniq.shape[0] < C:
+                lat, bram, dead = self._eval_rows(uniq)
+                lat, bram, dead = (lat[inverse], bram[inverse],
+                                   dead[inverse])
+                self.stats.n_dedup += C - uniq.shape[0]
+            else:
+                lat, bram, dead = self._eval_rows(depth_matrix)
         self.stats.n_calls += 1
         self.stats.n_configs += C
         self.stats.wall_s += time.perf_counter() - t_start

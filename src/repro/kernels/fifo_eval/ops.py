@@ -14,6 +14,11 @@ indices) come from the shared :func:`~repro.core.backends.operands
 ``use_ref=True``   the pure-jnp oracle (:mod:`repro.kernels.fifo_eval.ref`),
                    which is also the ``fixpoint`` backend's implementation
 
+Besides the results, the closure returns each row's Jacobi iteration
+count (lane 3 of the kernel's output row: on the Pallas kernel, the
+count of the row's 8-row block; on the oracle, the row's own).  Every
+``call`` waits for the device inside a ``fifo.wait`` span.
+
 Tests diff the two against each other and against the numpy worklist.
 Each returned ``call`` carries its jitted program as ``call.run`` so a
 caller can lower one dispatch (``call.run.lower(depths)``) and inspect
@@ -38,6 +43,7 @@ from repro.core.backends.operands import (bram_count_jnp, cert_row_operands,
 from repro.core.bram import (BRAM_READ_LATENCY, SRL_BITS, SRL_DEPTH,
                              SRL_READ_LATENCY)
 from repro.core.simgraph import SimGraph
+from repro.core.spans import span
 from repro.kernels.fifo_eval.fifo_eval import (fifo_eval_pallas,
                                                kernel_interpret,
                                                kernel_platform)
@@ -48,6 +54,11 @@ from repro.kernels.fifo_eval.ref import fifo_eval_ref, fifo_eval_ref_hetero
 #: that a fully-certifying batch costs exactly ONE "condensed" dispatch
 #: and never touches the host verifier.
 DISPATCH_COUNTS: Counter = Counter()
+
+#: the batched program returns each row's status in the low bits of an
+#: int32 whose upper bits hold the row's iteration count, so a launch
+#: brings back no more arrays than it did before iterations were counted
+_STATUS_BITS = 2
 
 
 def _shard_over_rows(run: Callable, mesh) -> Callable:
@@ -85,13 +96,14 @@ def _make_run(ops, inner, max_iters: int, with_times: bool) -> Callable:
         over = out[:, 2] > 0
         status = jnp.where(
             structural | over, DEADLOCK,
-            jnp.where(conv, CONVERGED, UNRESOLVED)).astype(jnp.int8)
+            jnp.where(conv, CONVERGED, UNRESOLVED)).astype(jnp.int32)
+        code = status | (out[:, 3].astype(jnp.int32) << _STATUS_BITS)
         bram = jnp.sum(bram_count_jnp(depths.astype(jnp.int32),
                                       ops.widths[None, :]),
                        axis=1).astype(jnp.int32)
         if with_times:
-            return lat, bram, status, times
-        return lat, bram, status
+            return lat, bram, code, times
+        return lat, bram, code
 
     return run
 
@@ -105,10 +117,10 @@ def make_batched_eval(ev_or_graph, use_ref: bool = False,
     Accepts either a :class:`~repro.core.simgraph.SimGraph` (raw or
     condensed — the condensation offsets ride the shared operands) or
     any object with ``.g`` / ``.max_iters`` (e.g. a ``BatchedEvaluator``).
-    With ``with_times`` the closure returns ``(lat, bram, status, t)``
-    where ``t`` is the (C, E_pad) final event-time matrix the
-    condensation certificate checks; otherwise ``(lat, bram, status)``
-    and the times are dead-code-eliminated inside the jit.
+    The closure returns ``(lat, bram, status, iters)``, ``iters`` being
+    each row's Jacobi iteration count; with ``with_times`` also ``t``,
+    the (C, E_pad) final event-time matrix the condensation certificate
+    checks, which is otherwise dead-code-eliminated inside the jit.
 
     ``mesh`` (a :class:`jax.sharding.Mesh`) shards the config-row axis
     across its devices via ``shard_map`` — see
@@ -133,8 +145,11 @@ def make_batched_eval(ev_or_graph, use_ref: bool = False,
     def call(depth_matrix: np.ndarray
              ) -> Tuple[np.ndarray, ...]:
         DISPATCH_COUNTS["batched"] += 1
-        return jax.device_get(
-            run(jnp.asarray(depth_matrix, dtype=jnp.int32)))
+        out = run(jnp.asarray(depth_matrix, dtype=jnp.int32))
+        with span("wait"):
+            lat, bram, code, *times = jax.device_get(out)
+        status = (code & ((1 << _STATUS_BITS) - 1)).astype(np.int8)
+        return (lat, bram, status, code >> _STATUS_BITS, *times)
 
     call.run = run
     return call
@@ -213,15 +228,17 @@ def make_condensed_eval(cg, max_iters: int = 64,
 
     def call(depth_matrix: np.ndarray) -> Tuple[np.ndarray, ...]:
         DISPATCH_COUNTS["condensed"] += 1
-        return jax.device_get(
-            run(jnp.asarray(depth_matrix, dtype=jnp.int32)))
+        out = run(jnp.asarray(depth_matrix, dtype=jnp.int32))
+        with span("wait"):
+            return jax.device_get(out)
 
     call.run = run
     return call
 
 
 def make_hetero_batched_eval(max_iters: int = 64, mesh=None,
-                             use_ref: Optional[bool] = None) -> Callable:
+                             use_ref: Optional[bool] = None,
+                             stats=None) -> Callable:
     """Build the CROSS-DESIGN batched evaluation closure.
 
     Consumes the stacked per-row batch dict produced by
@@ -240,7 +257,10 @@ def make_hetero_batched_eval(max_iters: int = 64, mesh=None,
 
     Returns ``call(batch) -> (latency i64, bram i64, status i8)``; the
     jit cache is keyed on the batch shape, so callers should bucket the
-    total row count (see ``HeteroDispatcher``).
+    total row count (see ``HeteroDispatcher``).  Given ``stats`` (a
+    ``HeteroStats``), each call adds the host's time until the launch is
+    enqueued (``fifo.hetero.h2d``) to ``stats.prep_s`` and its wait for
+    the results (``fifo.hetero.wait``) to ``stats.wait_s``.
 
     ``mesh`` shards the packed row batch over the mesh's devices — since
     every row carries its own event tables, the stacked batch is sharded
@@ -297,8 +317,10 @@ def make_hetero_batched_eval(max_iters: int = 64, mesh=None,
 
     def call(batch: dict) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         DISPATCH_COUNTS["hetero"] += 1
-        lat, bram, status = jax.device_get(
-            run({k: jnp.asarray(v) for k, v in batch.items()}))
+        with span("hetero.h2d", stats, "prep_s"):
+            out = run({k: jnp.asarray(v) for k, v in batch.items()})
+        with span("hetero.wait", stats, "wait_s"):
+            lat, bram, status = jax.device_get(out)
         lat = np.asarray(np.rint(lat), dtype=np.int64)
         return lat, np.asarray(bram, dtype=np.int64), np.asarray(status)
 

@@ -14,7 +14,7 @@ ShapeDtypeStructs — no arrays are ever allocated — then records:
     collective-permute result bytes, per device)
   * the three roofline terms vs TPU v5e constants (197 TF bf16,
     819 GB/s HBM, ~50 GB/s/link ICI), MODEL_FLOPS, and the useful-compute
-    ratio — consumed by benchmarks/roofline.py and EXPERIMENTS.md.
+    ratio.
 
 Usage:
   python -m repro.launch.dryrun --arch qwen2-1.5b --shape train_4k

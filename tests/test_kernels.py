@@ -50,8 +50,8 @@ def test_kernel_matches_ref_and_worklist(name, factory, batch):
     pallas_call = make_batched_eval(ev, max_iters=128)
     ref_call = make_batched_eval(ev, use_ref=True, max_iters=128)
 
-    lat_p, bram_p, st_p = pallas_call(cfgs)
-    lat_r, bram_r, st_r = ref_call(cfgs)
+    lat_p, bram_p, st_p, _ = pallas_call(cfgs)
+    lat_r, bram_r, st_r, _ = ref_call(cfgs)
     np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_r))
     np.testing.assert_array_equal(np.asarray(bram_p), np.asarray(bram_r))
     np.testing.assert_allclose(np.asarray(lat_p), np.asarray(lat_r))
@@ -131,10 +131,38 @@ def test_kernel_iteration_cap_reports_unresolved_not_wrong():
     ev = BatchedEvaluator(g, EvalConfig(backend="numpy", max_iters=64))
     call = make_batched_eval(ev, max_iters=2)
     cfgs = np.array([[40, 2], [2, 2]])
-    lat, _, st = call(cfgs)
+    lat, _, st, _ = call(cfgs)
     for i in range(2):
         if st[i] == 0:
             lat_np, dead_np = evaluate_np(g, cfgs[i])
             assert not dead_np and int(round(float(lat[i]))) == lat_np
         else:
             assert st[i] in (1, 2)
+
+
+def test_kernel_iteration_lane_is_the_block_max_of_the_reference():
+    """Lane 3 of the raw kernel's output is its 8-row block's Jacobi
+    iteration count: the largest of the jnp reference's per-row counts
+    over that block, on a batch mixing converged, deadlocked and capped
+    rows (mult_by_2(24) deadlocks below depth 23 on x)."""
+    g = build_simgraph(mult_by_2(24))
+    max_iters = 40
+    u = np.asarray(g.upper_bounds)
+    rng = np.random.default_rng(0)
+    pool = np.stack([u] + [rng.integers(2, np.maximum(3, u + 1))
+                           for _ in range(40)])
+    ref_call = make_batched_eval(g, use_ref=True, max_iters=max_iters)
+    pool_status = np.asarray(ref_call(pool)[2])
+    conv, dead, capped = (pool[pool_status == s] for s in (0, 1, 2))
+    assert len(conv) >= 3 and len(dead) >= 8 and len(capped) >= 2
+    # block 0 holds no capped row, so its count stays below the cap;
+    # block 1 holds one; the last block is ragged
+    cfgs = np.concatenate([conv[:2], dead[:6], capped[:1], conv[2:3],
+                           dead[6:8], capped[1:2]])
+    _, _, st_r, it_r = ref_call(cfgs)
+    _, _, st_p, it_p = make_batched_eval(g, max_iters=max_iters)(cfgs)
+    np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_r))
+    it_p, it_r = np.asarray(it_p), np.asarray(it_r)
+    for b in range(0, cfgs.shape[0], 8):
+        assert set(it_p[b:b + 8]) == {it_r[b:b + 8].max()}, b
+    assert it_p[0] < max_iters == it_p[8]
