@@ -20,7 +20,9 @@ Each layer opens a ``fifo.<name>`` profiler span (:mod:`repro.core.spans`)
 once per call: ``raw``, ``worklist``, ``rung.<tag>``, and the
 cross-design phases ``hetero.stack``, ``.pad``, ``.h2d``, ``.wait`` and
 ``.scatter``.  A raw-kernel launch's iteration lane is counted into
-``stats.raw_rows``, ``raw_row_iters`` and ``raw_tile_iters``.
+``stats.raw_rows``, ``raw_row_iters`` and ``raw_tile_iters``, its rows
+whose block walked its gathers instead of replaying their schedule into
+``raw_gather_fallbacks``.
 
 :class:`RungCascade` owns the condensation escalation ladder (moved here
 from ``BatchedEvaluator``): route each row through the most aggressive
@@ -55,14 +57,16 @@ BUCKETS = (1, 8, 32, 128, 512, 2048)
 
 
 def _count_iters(stats, backend: EvalBackend, rows: int) -> None:
-    """Add the iteration lane of ``backend``'s last raw-kernel launch,
-    whose first ``rows`` rows are real, to ``stats``."""
+    """Add the iteration and replayed lanes of ``backend``'s last
+    raw-kernel launch, whose first ``rows`` rows are real, to ``stats``."""
     iters = getattr(backend, "last_iters", None)
     if stats is None or iters is None:
         return
     stats.raw_rows += rows
     stats.raw_row_iters += int(iters[:rows].sum())
     stats.raw_tile_iters += backend.last_tile_iters
+    stats.raw_gather_fallbacks += int(
+        (~backend.last_replayed[:rows]).sum())
 
 
 class DispatchPolicy:

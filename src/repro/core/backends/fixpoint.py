@@ -53,11 +53,12 @@ class _ScanBackend(EvalBackend):
     #: a jax.sharding.Mesh to shard the config-row axis over (None = solo
     #: jit on the default device); set by the MeshBackend subclass
     mesh = None
-    #: the Pallas raw kernel's iteration lane for each row of the last
-    #: ``evaluate`` / ``evaluate_with_times`` batch, and that launch's
-    #: vreg-tile iterations (see :meth:`_note_iters`); None on the jnp
-    #: reference, which runs no kernel blocks
+    #: the Pallas raw kernel's iteration and replayed lanes for each row
+    #: of the last ``evaluate`` / ``evaluate_with_times`` batch, and that
+    #: launch's vreg-tile iterations (see :meth:`_note_iters`); None on
+    #: the jnp reference, which runs no kernel blocks
     last_iters = None
+    last_replayed = None
     last_tile_iters = 0
 
     @property
@@ -122,15 +123,18 @@ class _ScanBackend(EvalBackend):
         return (lat, bram, np.asarray(status[:c], dtype=np.int8),
                 np.asarray(cert[:c], dtype=bool))
 
-    def _note_iters(self, iters: np.ndarray, c: int) -> None:
-        """Keep lane 3 of a raw-kernel launch: every row of an 8-row
-        block carries the block's Jacobi iterations, and each iteration
-        of a block steps one f32 vreg tile per 128 events."""
+    def _note_iters(self, iters: np.ndarray, replayed: np.ndarray,
+                    c: int) -> None:
+        """Keep lanes 3 and 4 of a raw-kernel launch: every row of an
+        8-row block carries the block's Jacobi iterations and whether it
+        replayed its gather schedule, and each iteration of a block
+        steps one f32 vreg tile per 128 events."""
         if self.use_ref:
             return
         from repro.kernels.fifo_eval.fifo_eval import LANES, ROWS
         iters = np.asarray(iters, dtype=np.int64)
         self.last_iters = iters[:c]
+        self.last_replayed = np.asarray(replayed, dtype=bool)[:c]
         # under a mesh each device blocks its own row shard
         blocks = sum(int(s[::ROWS].sum())
                      for s in np.split(iters, self.shard_multiple))
@@ -140,8 +144,8 @@ class _ScanBackend(EvalBackend):
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
         m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int32))
         m, c = self._pad_shards(m)
-        lat, bram, status, iters = self._call(m)
-        self._note_iters(iters, c)
+        lat, bram, status, iters, replayed = self._call(m)
+        self._note_iters(iters, replayed, c)
         lat = np.asarray(np.rint(lat[:c]), dtype=np.int64)
         bram = np.asarray(bram[:c], dtype=np.int64)
         return lat, bram, np.asarray(status[:c], dtype=np.int8)
@@ -158,8 +162,8 @@ class _ScanBackend(EvalBackend):
                 with_times=True, mesh=self.mesh)
         m = np.atleast_2d(np.asarray(depth_matrix, dtype=np.int32))
         m, c = self._pad_shards(m)
-        lat, bram, status, iters, times = self._call_times(m)
-        self._note_iters(iters, c)
+        lat, bram, status, iters, replayed, times = self._call_times(m)
+        self._note_iters(iters, replayed, c)
         lat = np.asarray(np.rint(lat[:c]), dtype=np.int64)
         bram = np.asarray(bram[:c], dtype=np.int64)
         times = np.asarray(np.rint(times[:c]), dtype=np.int64)

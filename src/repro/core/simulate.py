@@ -76,6 +76,7 @@ class BatchStats:
     raw_rows: int = 0         # real rows of fifo_eval_raw launches
     raw_row_iters: int = 0    # their Jacobi iterations (the kernel's lane 3)
     raw_tile_iters: int = 0   # 8-row block iterations x E_pad / 128 vregs
+    raw_gather_fallbacks: int = 0  # their rows that walked, not replayed
 
 
 #: historical BatchedEvaluator default (the advisor default is 256)

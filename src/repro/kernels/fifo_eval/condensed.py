@@ -48,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.fifo_eval.fifo_eval import (LANES, OUT_LANES, VMEM_LIMIT,
                                                _fixpoint, _gather,
-                                               _result_rows)
+                                               _result_rows,
+                                               schedule_scratch)
 
 #: default configurations per grid program.  Condensed tiles are narrow
 #: (Ec_pad is 128-512 where raw graphs run 8k-13k events), so a block of
@@ -86,16 +87,17 @@ def _condensed_kernel(
     # per-config (BLOCK, V) certificate slots
     csrc_ref, cdst_ref, cthr_ref, cval_ref,
     # outputs (result rows, then with_times the final event times), then
-    # scratch: three (BLOCK, E) and two (BLOCK, V) f32 tiles
+    # scratch: three (BLOCK, E) and two (BLOCK, V) f32 tiles and the two
+    # gather schedules
     *refs,
     max_iters: int, bound: float, with_times: bool,
 ):
     out_ref = refs[0]
-    t_ref, td_ref, tb_ref, ts_ref, tq_ref = refs[-5:]
-    iters, conv, over = _fixpoint(
+    t_ref, td_ref, tb_ref, ts_ref, tq_ref, dsched_ref, bsched_ref = refs[-7:]
+    iters, conv, over, _ = _fixpoint(
         delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref,
         rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref, t_ref, td_ref, tb_ref,
-        max_iters=max_iters, bound=bound)
+        dsched_ref, bsched_ref, max_iters=max_iters, bound=bound)
 
     # fused exactness certificate: slot v of row c is violated iff
     # valid and t[src] - t[dst] > thr (all-integer f32, exact < 2**24)
@@ -155,7 +157,8 @@ def fifo_eval_condensed(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=([pltpu.VMEM((block, e_pad), jnp.float32)] * 3
-                        + [pltpu.VMEM((block, v_pad), jnp.float32)] * 2),
+                        + [pltpu.VMEM((block, v_pad), jnp.float32)] * 2
+                        + [schedule_scratch(e_pad)] * 2),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="fifo_eval_condensed",

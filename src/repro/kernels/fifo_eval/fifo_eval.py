@@ -15,18 +15,28 @@ the iteration cap; finished rows are frozen while the rest keep stepping,
 so each row's result equals its own solo fixpoint.
 
 Gathers: Mosaic gathers lanes only within one 128-lane vreg
-(``tpu.dynamic_gather``), so :func:`_gather` walks each 128-lane output
-chunk over the DISTINCT 128-lane source chunks its indices reference —
-one in-vreg lane gather plus a select per (output chunk, source chunk)
-pair.  Pure data movement: exact for any f32 value.  Trace-ordered
-dataflow keeps the distinct-source count per chunk small (a chunk of
-reads draws from the few writer segments feeding it).
+(``tpu.dynamic_gather``), so each 128-lane output chunk is assembled
+from the DISTINCT 128-lane source chunks its indices reference — one
+in-vreg lane gather plus a select per (output chunk, source chunk) pair.
+Pure data movement: exact for any f32 value.  Trace-ordered dataflow
+keeps the distinct-source count per chunk small (a chunk of reads draws
+from the few writer segments feeding it).  The pairs never change within
+a launch (data-edge indices are fixed per graph, back-pressure indices
+per row), so :func:`_schedule` finds them once per grid program, before
+the Jacobi loop, and records each output chunk's sources, smallest
+first, in an SMEM table of ``GATHER_K`` (64) slots a chunk; every
+iteration :func:`_replay` loads exactly those chunks, with no cross-lane
+reduction in the loop.  A block in which some output chunk of either
+in-loop table names more source chunks than it has slots walks them
+anew each iteration (:func:`_gather`) for the whole launch, and says so
+in lane 4.
 
 The kernels compile with Mosaic on a TPU and run in the Pallas
 interpreter on the CPU (tests); :func:`interpret_for` is the single rule.
 
 Layout of the per-config output row (float32, 128 lanes):
     [0] latency   [1] converged (0/1)   [2] over-bound (0/1)   [3] iters
+    [4] replayed (0/1: the block's gathers replayed their schedule)
 """
 
 from __future__ import annotations
@@ -51,6 +61,16 @@ ROWS = 8
 #: VMEM; the default scoped limit of 16 MiB is too small for the raw
 #: kernel at 33k events or the condensed kernel's certificate tiles)
 VMEM_LIMIT = 64 * 2**20
+#: source-chunk slots a gather schedule keeps per 128-lane output chunk.
+#: k15mmtree_relu's output chunks read at most 5 source chunks through
+#: its data edges and 17 through the back-pressure edges of the 8-row
+#: blocks its grouped_random and grouped_sa searches send; 8 rows of
+#: uniformly random depths reach 36, and a cross-design block that
+#: straddles two designs 65.
+GATHER_K = 64
+#: SMEM words one gather schedule may take: a kernel keeps two, and a
+#: TPU v5e core has 1 MiB of SMEM
+_SCHEDULE_WORDS = 2**16
 
 
 def interpret_for(platform: str) -> bool:
@@ -101,21 +121,34 @@ def _seg_scan(a, m, n_steps: int):
     return a, m
 
 
+def _chunk_index(idx_ref, o, rows: int, n_src: int):
+    """Column slice of output chunk ``o`` and its indices split into
+    (source chunk, lane within it), each (rows, LANES)."""
+    col = pl.ds(pl.multiple_of(o * LANES, LANES), LANES)
+    idx = jnp.broadcast_to(idx_ref[:, col], (rows, LANES))
+    hi = jnp.minimum(lax.shift_right_logical(idx, _LANE_BITS), n_src - 1)
+    return col, hi, idx & (LANES - 1)
+
+
+def _load_from(src_ref, s, lo):
+    """Lanes ``lo`` of 128-lane source chunk ``s`` (a scalar)."""
+    src = src_ref[:, pl.ds(pl.multiple_of(s * LANES, LANES), LANES)]
+    return jnp.take_along_axis(src, lo, axis=1, mode="promise_in_bounds")
+
+
 def _gather(src_ref, idx_ref, dst_ref):
     """``dst[r, j] = src[r, idx[r, j]]`` for every row of the block.
 
     ``idx_ref`` is (1, N) (shared by all rows) or (rows, N); every index
-    must lie in ``[0, src width)``.  Each 128-lane output chunk visits
-    only the distinct source chunks its indices name, smallest first.
+    must lie in ``[0, src width)``.  Each 128-lane output chunk walks
+    the distinct source chunks its indices name, smallest first, finding
+    each with a lane reduction.
     """
     rows, n_src = src_ref.shape[0], src_ref.shape[1] // LANES
     n_out = dst_ref.shape[1] // LANES
 
     def out_chunk(o, carry):
-        col = pl.ds(pl.multiple_of(o * LANES, LANES), LANES)
-        idx = jnp.broadcast_to(idx_ref[:, col], (rows, LANES))
-        hi = jnp.minimum(lax.shift_right_logical(idx, _LANE_BITS), n_src - 1)
-        lo = idx & (LANES - 1)
+        col, hi, lo = _chunk_index(idx_ref, o, rows, n_src)
 
         def pending(state):
             # each pass retires >= 1 source chunk: n_src passes at most
@@ -124,11 +157,9 @@ def _gather(src_ref, idx_ref, dst_ref):
         def src_chunk(state):
             k, got, left = state
             s = jnp.min(jnp.where(left > 0, hi, n_src))
-            src = src_ref[:, pl.ds(pl.multiple_of(s * LANES, LANES), LANES)]
-            val = jnp.take_along_axis(src, lo, axis=1,
-                                      mode="promise_in_bounds")
             hit = (left > 0) & (hi == s)
-            return k + 1, jnp.where(hit, val, got), jnp.where(hit, 0, left)
+            return (k + 1, jnp.where(hit, _load_from(src_ref, s, lo), got),
+                    jnp.where(hit, 0, left))
 
         _, got, _ = lax.while_loop(
             pending, src_chunk,
@@ -140,23 +171,107 @@ def _gather(src_ref, idx_ref, dst_ref):
     lax.fori_loop(0, n_out, out_chunk, 0)
 
 
+def _slots(n_out: int) -> int:
+    """Source-chunk slots per output chunk of a schedule over ``n_out``
+    output chunks: ``GATHER_K``, fewer where the table would pass
+    ``_SCHEDULE_WORDS`` (graphs of more than about 250k events)."""
+    return max(1, min(GATHER_K, _SCHEDULE_WORDS // n_out - 1))
+
+
+def schedule_scratch(e_pad: int):
+    """SMEM scratch for one gather schedule over ``e_pad`` output
+    events: per 128-lane output chunk, its source-chunk count and then
+    :func:`_slots` source-chunk slots."""
+    n_out = e_pad // LANES
+    return pltpu.SMEM((n_out * (_slots(n_out) + 1),), jnp.int32)
+
+
+def _schedule(rows: int, n_src: int, idx_ref, sched_ref):
+    """Record in ``sched_ref`` (:func:`schedule_scratch`) the distinct
+    source chunks that each output chunk of ``idx_ref`` reads, smallest
+    first, as :func:`_gather` walks them; returns whether every chunk
+    fit in its slots.  The last slot of a chunk that does not fit is
+    overwritten, and such a schedule must not be replayed."""
+    n_out = idx_ref.shape[1] // LANES
+    slots = _slots(n_out)
+    stride = slots + 1
+
+    def out_chunk(o, widest):
+        _, hi, _ = _chunk_index(idx_ref, o, rows, n_src)
+
+        def pending(state):
+            return (state[0] < n_src) & (jnp.max(state[1]) > 0)
+
+        def src_chunk(state):
+            k, left = state
+            s = jnp.min(jnp.where(left > 0, hi, n_src))
+            sched_ref[o * stride + 1 + jnp.minimum(k, slots - 1)] = s
+            return k + 1, jnp.where(hi == s, 0, left)
+
+        k, _ = lax.while_loop(pending, src_chunk,
+                              (jnp.int32(0),
+                               jnp.ones((rows, LANES), jnp.int32)))
+        sched_ref[o * stride] = k
+        return jnp.maximum(widest, k)
+
+    return lax.fori_loop(0, n_out, out_chunk, jnp.int32(0)) <= slots
+
+
+def _replay(src_ref, idx_ref, dst_ref, sched_ref):
+    """:func:`_gather` from the schedule :func:`_schedule` recorded for
+    ``idx_ref``: each output chunk loads the source chunks named in its
+    SMEM row, with no lane reduction.  Bit-identical to the walk, since
+    every lane's source chunk is among them."""
+    rows, n_src = src_ref.shape[0], src_ref.shape[1] // LANES
+    n_out = dst_ref.shape[1] // LANES
+    stride = _slots(n_out) + 1
+
+    def out_chunk(o, carry):
+        col, hi, lo = _chunk_index(idx_ref, o, rows, n_src)
+
+        def src_chunk(k, got):
+            s = sched_ref[o * stride + 1 + k]
+            return jnp.where(hi == s, _load_from(src_ref, s, lo), got)
+
+        dst_ref[:, col] = lax.fori_loop(
+            0, sched_ref[o * stride], src_chunk,
+            jnp.zeros((rows, LANES), jnp.float32))
+        return carry
+
+    lax.fori_loop(0, n_out, out_chunk, 0)
+
+
 def _fixpoint(delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref,
               rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref,
-              t_ref, td_ref, tb_ref, *, max_iters: int, bound: float):
+              t_ref, td_ref, tb_ref, dsched_ref, bsched_ref, *,
+              max_iters: int, bound: float):
     """Row-frozen Jacobi fixpoint of one row block, left in ``t_ref``.
 
     Operand refs are (1, E) when shared by the block's rows, else (rows,
     E); ``bound`` is a float or a (rows, 1) array of per-row bounds;
     ``td_ref`` / ``tb_ref`` are (rows, E) scratch for the gathered data
-    and back-pressure sources.  Returns ``(iters, conv, over)`` with the
-    flags as (rows, 1) f32 0/1 columns.
+    and back-pressure sources, ``dsched_ref`` / ``bsched_ref`` their
+    gather schedules (:func:`schedule_scratch`).  Returns ``(iters,
+    conv, over, replayed)`` with the flags as (rows, 1) f32 0/1 columns
+    and ``replayed`` a scalar: whether the gathers replayed their
+    schedules rather than walking every iteration.
     """
     rows, e_pad = t_ref.shape
     n_steps = _num_scan_steps(e_pad)
+    replayed = (_schedule(rows, e_pad // LANES, didx_ref, dsched_ref)
+                & _schedule(rows, e_pad // LANES, bpidx_ref, bsched_ref))
 
     def step():
-        _gather(t_ref, didx_ref, td_ref)              # shared data edges
-        _gather(t_ref, bpidx_ref, tb_ref)             # per-row bp edges
+        @pl.when(replayed)
+        def _():
+            _replay(t_ref, didx_ref, td_ref, dsched_ref)   # data edges
+            _replay(t_ref, bpidx_ref, tb_ref, bsched_ref)  # bp edges
+
+        @pl.when(jnp.logical_not(replayed))
+        def _():
+            _gather(t_ref, didx_ref, td_ref)
+            _gather(t_ref, bpidx_ref, tb_ref)
+
         bd = jnp.where(hasdata_ref[...] > 0, td_ref[...] + rdlat_ref[...],
                        NEG)
         bb = jnp.where(bpval_ref[...] > 0, tb_ref[...] + bpbase_ref[...],
@@ -188,7 +303,9 @@ def _fixpoint(delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref,
 
     t_ref[...] = jnp.zeros((rows, e_pad), jnp.float32)
     flags = jnp.zeros((rows, 1), jnp.float32)
-    return lax.while_loop(cond, body, (jnp.int32(0), flags, flags))
+    iters, conv, over = lax.while_loop(cond, body,
+                                       (jnp.int32(0), flags, flags))
+    return iters, conv, over, replayed
 
 
 def _result_rows(*cols):
@@ -207,7 +324,7 @@ def _fifo_eval_kernel(*refs, max_iters: int, bound, with_times: bool):
     shared or (ROWS, E) per-config; when ``bound`` is None the per-row
     deadlock bounds, each repeated along a (ROWS, LANES) row; the outputs
     (result rows, then with_times the final event times); three (ROWS, E)
-    f32 scratch tiles."""
+    f32 scratch tiles and the two gather schedules."""
     (delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref, endb_ref,
      rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref) = refs[:10]
     rest = refs[10:]
@@ -217,14 +334,15 @@ def _fifo_eval_kernel(*refs, max_iters: int, bound, with_times: bool):
         bound = jnp.max(rest[0][...], axis=1, keepdims=True)
         rest = rest[1:]
     out_ref = rest[0]
-    t_ref, td_ref, tb_ref = rest[-3:]
-    iters, conv, over = _fixpoint(
+    t_ref, td_ref, tb_ref, dsched_ref, bsched_ref = rest[-5:]
+    iters, conv, over, replayed = _fixpoint(
         delta_ref, segst_ref, isread_ref, hasdata_ref, didx_ref,
         rdlat_ref, bpidx_ref, bpval_ref, bpbase_ref, t_ref, td_ref, tb_ref,
-        max_iters=max_iters, bound=bound)
+        dsched_ref, bsched_ref, max_iters=max_iters, bound=bound)
     t = t_ref[...]
     latency = jnp.max(t + endb_ref[...], axis=1, keepdims=True)
-    out_ref[...] = _result_rows(latency, conv, over, iters)
+    out_ref[...] = _result_rows(latency, conv, over, iters,
+                                replayed.astype(jnp.int32))
     if with_times:
         rest[1][...] = t
 
@@ -290,7 +408,8 @@ def fifo_eval_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((ROWS, e_pad), jnp.float32)] * 3,
+        scratch_shapes=([pltpu.VMEM((ROWS, e_pad), jnp.float32)] * 3
+                        + [schedule_scratch(e_pad)] * 2),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT),
         interpret=interpret,
         name="fifo_eval_raw",

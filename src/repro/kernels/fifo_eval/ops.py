@@ -16,8 +16,10 @@ indices) come from the shared :func:`~repro.core.backends.operands
 
 Besides the results, the closure returns each row's Jacobi iteration
 count (lane 3 of the kernel's output row: on the Pallas kernel, the
-count of the row's 8-row block; on the oracle, the row's own).  Every
-``call`` waits for the device inside a ``fifo.wait`` span.
+count of the row's 8-row block; on the oracle, the row's own) and
+whether the row's block replayed its gather schedule (lane 4; never on
+the oracle, which has none).  Every ``call`` waits for the device inside
+a ``fifo.wait`` span.
 
 Tests diff the two against each other and against the numpy worklist.
 Each returned ``call`` carries its jitted program as ``call.run`` so a
@@ -56,9 +58,11 @@ from repro.kernels.fifo_eval.ref import fifo_eval_ref, fifo_eval_ref_hetero
 DISPATCH_COUNTS: Counter = Counter()
 
 #: the batched program returns each row's status in the low bits of an
-#: int32 whose upper bits hold the row's iteration count, so a launch
-#: brings back no more arrays than it did before iterations were counted
+#: int32, then the replayed bit, then the row's iteration count, so a
+#: launch brings back no more arrays than it did before either was kept
 _STATUS_BITS = 2
+_REPLAYED = 1 << _STATUS_BITS
+_ITER_SHIFT = _STATUS_BITS + 1
 
 
 def _shard_over_rows(run: Callable, mesh) -> Callable:
@@ -97,7 +101,9 @@ def _make_run(ops, inner, max_iters: int, with_times: bool) -> Callable:
         status = jnp.where(
             structural | over, DEADLOCK,
             jnp.where(conv, CONVERGED, UNRESOLVED)).astype(jnp.int32)
-        code = status | (out[:, 3].astype(jnp.int32) << _STATUS_BITS)
+        code = status | (out[:, 3].astype(jnp.int32) << _ITER_SHIFT)
+        if out.shape[1] > 4:                 # the kernel's replayed lane
+            code = code | jnp.where(out[:, 4] > 0, _REPLAYED, 0)
         bram = jnp.sum(bram_count_jnp(depths.astype(jnp.int32),
                                       ops.widths[None, :]),
                        axis=1).astype(jnp.int32)
@@ -117,8 +123,10 @@ def make_batched_eval(ev_or_graph, use_ref: bool = False,
     Accepts either a :class:`~repro.core.simgraph.SimGraph` (raw or
     condensed — the condensation offsets ride the shared operands) or
     any object with ``.g`` / ``.max_iters`` (e.g. a ``BatchedEvaluator``).
-    The closure returns ``(lat, bram, status, iters)``, ``iters`` being
-    each row's Jacobi iteration count; with ``with_times`` also ``t``,
+    The closure returns ``(lat, bram, status, iters, replayed)``,
+    ``iters`` being each row's Jacobi iteration count and ``replayed``
+    whether its block replayed the gather schedule (False on the
+    oracle); with ``with_times`` also ``t``,
     the (C, E_pad) final event-time matrix the condensation certificate
     checks, which is otherwise dead-code-eliminated inside the jit.
 
@@ -149,7 +157,8 @@ def make_batched_eval(ev_or_graph, use_ref: bool = False,
         with span("wait"):
             lat, bram, code, *times = jax.device_get(out)
         status = (code & ((1 << _STATUS_BITS) - 1)).astype(np.int8)
-        return (lat, bram, status, code >> _STATUS_BITS, *times)
+        return (lat, bram, status, code >> _ITER_SHIFT,
+                (code & _REPLAYED) > 0, *times)
 
     call.run = run
     return call

@@ -50,8 +50,8 @@ def test_kernel_matches_ref_and_worklist(name, factory, batch):
     pallas_call = make_batched_eval(ev, max_iters=128)
     ref_call = make_batched_eval(ev, use_ref=True, max_iters=128)
 
-    lat_p, bram_p, st_p, _ = pallas_call(cfgs)
-    lat_r, bram_r, st_r, _ = ref_call(cfgs)
+    lat_p, bram_p, st_p, _, _ = pallas_call(cfgs)
+    lat_r, bram_r, st_r, _, _ = ref_call(cfgs)
     np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_r))
     np.testing.assert_array_equal(np.asarray(bram_p), np.asarray(bram_r))
     np.testing.assert_allclose(np.asarray(lat_p), np.asarray(lat_r))
@@ -131,7 +131,7 @@ def test_kernel_iteration_cap_reports_unresolved_not_wrong():
     ev = BatchedEvaluator(g, EvalConfig(backend="numpy", max_iters=64))
     call = make_batched_eval(ev, max_iters=2)
     cfgs = np.array([[40, 2], [2, 2]])
-    lat, _, st, _ = call(cfgs)
+    lat, _, st, _, _ = call(cfgs)
     for i in range(2):
         if st[i] == 0:
             lat_np, dead_np = evaluate_np(g, cfgs[i])
@@ -159,10 +159,112 @@ def test_kernel_iteration_lane_is_the_block_max_of_the_reference():
     # block 1 holds one; the last block is ragged
     cfgs = np.concatenate([conv[:2], dead[:6], capped[:1], conv[2:3],
                            dead[6:8], capped[1:2]])
-    _, _, st_r, it_r = ref_call(cfgs)
-    _, _, st_p, it_p = make_batched_eval(g, max_iters=max_iters)(cfgs)
+    _, _, st_r, it_r, _ = ref_call(cfgs)
+    _, _, st_p, it_p, _ = make_batched_eval(g, max_iters=max_iters)(cfgs)
     np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_r))
     it_p, it_r = np.asarray(it_p), np.asarray(it_r)
     for b in range(0, cfgs.shape[0], 8):
         assert set(it_p[b:b + 8]) == {it_r[b:b + 8].max()}, b
     assert it_p[0] < max_iters == it_p[8]
+
+
+def _gathers(src, idx):
+    """The walk and the replayed schedule of one (8, N) block on the
+    Pallas interpreter: ``(walked, replayed, fit)``; ``replayed`` is
+    zeros where the schedule did not fit its slots."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from repro.kernels.fifo_eval import fifo_eval as fe
+
+    def kernel(src_ref, idx_ref, walk_ref, replay_ref, fit_ref, sched_ref):
+        fe._gather(src_ref, idx_ref, walk_ref)
+        fit = fe._schedule(src_ref.shape[0], src_ref.shape[1] // fe.LANES,
+                           idx_ref, sched_ref)
+        replay_ref[...] = jnp.zeros(replay_ref.shape, jnp.float32)
+
+        @pl.when(fit)
+        def _():
+            fe._replay(src_ref, idx_ref, replay_ref, sched_ref)
+        fit_ref[...] = jnp.full(fit_ref.shape, fit.astype(jnp.int32))
+
+    n = idx.shape[1]
+    walked, replayed, fit = pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((8, n), jnp.float32)] * 2
+        + [jax.ShapeDtypeStruct((8, fe.LANES), jnp.int32)],
+        scratch_shapes=[fe.schedule_scratch(n)],
+        interpret=True,
+    )(jnp.asarray(src), jnp.asarray(idx))
+    return np.asarray(walked), np.asarray(replayed), bool(fit[0, 0])
+
+
+@pytest.mark.parametrize("table, slack", [
+    ("shared", None), ("per_row", None),
+    ("per_row", 0),          # the widest chunk fills its slots exactly
+    ("per_row", -1),         # ... and has one source more than its slots
+])
+def test_replayed_gather_equals_the_walk(monkeypatch, table, slack):
+    """The schedule found once and replayed gives the walk's gather bit
+    for bit (on every f32 pattern, NaNs included) for a shared (1, E) and
+    a per-row (8, E) index table; where an output chunk names more
+    source chunks than its slots the schedule reports that it does not
+    fit, and the walk still gathers exactly."""
+    from repro.kernels.fifo_eval import fifo_eval as fe
+    rng = np.random.default_rng(5)
+    e = 6 * fe.LANES
+    src = rng.integers(0, 2**32, size=(8, e), dtype=np.uint64).astype(
+        np.uint32).view(np.float32)
+    # each output chunk reads a few source chunks near its own, as the
+    # trace-ordered tables do
+    near = np.arange(e) // fe.LANES
+    rows = 1 if table == "shared" else 8
+    chunk = np.clip(near + rng.integers(-2, 3, size=(rows, e)), 0, 5)
+    idx = (chunk * fe.LANES
+           + rng.integers(0, fe.LANES, size=(rows, e))).astype(np.int32)
+    widest = max(len(np.unique(c)) for c in
+                 np.split(chunk, e // fe.LANES, axis=1))
+    assert 1 < widest < fe.GATHER_K
+    if slack is not None:
+        monkeypatch.setattr(fe, "GATHER_K", widest + slack)
+    walked, replayed, fit = _gathers(src, idx)
+    want = np.take_along_axis(src, np.broadcast_to(idx, (8, e)), axis=1)
+    np.testing.assert_array_equal(walked.view(np.uint32),
+                                  want.view(np.uint32))
+    assert fit == (slack != -1)
+    if fit:
+        np.testing.assert_array_equal(replayed.view(np.uint32),
+                                      want.view(np.uint32))
+
+
+@pytest.mark.parametrize("design, gather_k, replays", [
+    ("k15mmtree_relu", None, True),
+    ("atax", None, True),
+    ("atax", 1, False),      # every block walks its gathers
+])
+def test_raw_kernel_replay_matches_reference(monkeypatch, design, gather_k,
+                                             replays):
+    """The raw kernel on rows with random depths returns the jnp
+    reference's latency and status and, per 8-row block, the largest of
+    its iteration counts, whether its blocks replay their gather
+    schedules or (with too few slots) walk them; the replayed lane says
+    which."""
+    from repro.designs import make_design
+    from repro.kernels.fifo_eval import fifo_eval as fe
+    if gather_k is not None:
+        monkeypatch.setattr(fe, "GATHER_K", gather_k)
+    g = build_simgraph(make_design(design))
+    u = np.asarray(g.upper_bounds)
+    rng = np.random.default_rng(2)
+    cfgs = np.stack([u] + [rng.integers(2, np.maximum(3, u + 1))
+                           for _ in range(8)]).astype(np.int32)
+    lat_r, _, st_r, it_r, rep_r = make_batched_eval(
+        g, use_ref=True, max_iters=256)(cfgs)
+    lat_p, _, st_p, it_p, rep_p = make_batched_eval(
+        g, max_iters=256)(cfgs)
+    np.testing.assert_array_equal(np.asarray(lat_p), np.asarray(lat_r))
+    np.testing.assert_array_equal(np.asarray(st_p), np.asarray(st_r))
+    for b in range(0, cfgs.shape[0], 8):
+        assert set(it_p[b:b + 8]) == {it_r[b:b + 8].max()}, b
+    assert set(np.asarray(rep_p)) == {replays}
+    assert not np.asarray(rep_r).any()

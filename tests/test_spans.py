@@ -34,16 +34,39 @@ def test_batch_stats_count_the_raw_launch(max_iters, escalates):
     ev = BatchedEvaluator(g, EvalConfig(backend="pallas", condense=None,
                                         max_iters=max_iters))
     ev.evaluate(cfgs)
-    _, _, status, iters = make_batched_eval(g, max_iters=max_iters)(cfgs)
+    _, _, status, iters, _ = make_batched_eval(g, max_iters=max_iters)(
+        cfgs)
     status, iters = np.asarray(status), np.asarray(iters)
     e_pad = ev._impl.ops.e_pad
     s = ev.stats
     assert s.raw_rows == cfgs.shape[0]
     assert s.raw_row_iters == iters.sum()
     assert s.raw_tile_iters == iters[::ROWS].sum() * (e_pad // LANES)
+    assert s.raw_gather_fallbacks == 0
     assert s.n_fallbacks == (status == 2).sum()
     assert (s.n_fallbacks > 0) == escalates
     assert (s.worklist_s > 0) == escalates
+
+
+@pytest.mark.parametrize("gather_k, walks", [(None, False), (1, True)])
+def test_batch_stats_count_rows_that_walk_their_gathers(monkeypatch,
+                                                        gather_k, walks):
+    """``raw_gather_fallbacks`` counts the real rows whose block walked
+    its gathers: none where the schedules fit their slots, every row
+    where no output chunk of atax's several may keep more than one
+    source chunk."""
+    from repro.designs import make_design
+    from repro.kernels.fifo_eval import fifo_eval as fe
+    if gather_k is not None:
+        monkeypatch.setattr(fe, "GATHER_K", gather_k)
+    g = build_simgraph(make_design("atax"))
+    u = np.asarray(g.upper_bounds)
+    cfgs = np.stack([u, np.maximum(2, u // 2), np.full_like(u, 2)])
+    ev = BatchedEvaluator(g, EvalConfig(backend="pallas", condense=None,
+                                        max_iters=64))
+    ev.evaluate(cfgs)
+    assert ev.stats.raw_rows == 3
+    assert ev.stats.raw_gather_fallbacks == (3 if walks else 0)
 
 
 def test_hetero_stats_time_each_dispatch():
