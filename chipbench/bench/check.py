@@ -52,10 +52,12 @@ def _sample_rows(rng, res, n_other: int) -> List[int]:
 
 def compare(answers: Sequence[Tuple[str, object]], designs: Dict[str, dict],
             seed: int, n_results: int, n_rows: int, min_rows: int,
-            control: bool = False) -> Dict[str, dict]:
+            control: bool = False, bench: str = reference.BENCH
+            ) -> Dict[str, dict]:
     """``answers`` are ``(design name, DseResult)`` in completion order;
-    ``designs`` maps each design name to its stage list.  Returns each
-    number compared with its limit."""
+    ``designs`` maps each design name to its stage list, whose file
+    stages are found under ``bench``.  Returns each number compared with
+    its limit."""
     rng = np.random.default_rng([abs(int(seed)), 0x5EED])
     built: Dict[str, reference.Design] = {}
     picks = sorted(rng.permutation(len(answers))[:n_results])
@@ -64,7 +66,9 @@ def compare(answers: Sequence[Tuple[str, object]], designs: Dict[str, dict],
     for k in picks:
         name, dse = answers[k]
         res = dse.result
-        design = built.setdefault(name, reference.Design(designs[name]))
+        if name not in built:
+            built[name] = reference.Design(designs[name], bench)
+        design = built[name]
         rows = _sample_rows(rng, res, per)
         for i in rows:
             want = reference.answer(design, res.configs[i])

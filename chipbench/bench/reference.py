@@ -6,7 +6,12 @@ stages (Stream-HLS loaders, stores and pipelined loop nests, written
 from the published kernels by ``chipbench/tools/stage_lists.py``); this
 module turns each stage into
 its sequence of FIFO operations and simulates the design at one depth
-vector with the timing contract of the FIFOAdvisor paper:
+vector.  A stage that is none of the nine built in here is a builder
+found by file: ``"stage": "pna.scatter"`` is ``scatter(fifos, rec)`` of
+``chipbench/stages/pna.py`` (:func:`bench.spec.stage`), so a
+data-dependent design, whose tasks' op counts follow its input data,
+arrives as new files only.  The timing contract is the FIFOAdvisor
+paper's:
 
 * op ``i`` of a task completes no earlier than ``t[i-1] + delay[i]``;
 * the k-th read of FIFO ``f`` completes no earlier than
@@ -29,6 +34,8 @@ from __future__ import annotations
 
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+from bench.spec import BENCH, stage as find_stage
+
 READ, WRITE = 0, 1
 
 #: BRAM18K (depth, width) aspect ratios, widest first
@@ -40,9 +47,10 @@ SRL_DEPTH = 2
 class Design:
     """A design's FIFO operations: ``tasks[t]`` is a list of
     ``(kind, fifo, delay)`` triples and ``trailing[t]`` the delay after
-    its last op; ``widths[f]`` is FIFO f's element width in bits."""
+    its last op; ``widths[f]`` is FIFO f's element width in bits.
+    ``bench`` is the directory whose ``stages/`` holds the stage files."""
 
-    def __init__(self, spec: dict):
+    def __init__(self, spec: dict, bench: str = BENCH):
         self.name = spec["name"]
         self.fifos: Dict[str, List[int]] = {}
         self.widths: List[int] = []
@@ -52,8 +60,11 @@ class Design:
             self.widths += [int(s["width"])] * s["lanes"]
         self.tasks: List[List[Tuple[int, int, int]]] = []
         self.trailing: List[int] = []
+        builders = dict(_STAGES)
         for rec in spec["tasks"]:
-            ops, trailing = _STAGES[rec["stage"]](self.fifos, rec)
+            if rec["stage"] not in builders:
+                builders[rec["stage"]] = find_stage(bench, rec["stage"])
+            ops, trailing = builders[rec["stage"]](self.fifos, rec)
             self.tasks.append(ops)
             self.trailing.append(trailing)
 
@@ -86,6 +97,10 @@ class _Ops:
 
     def done(self):
         return self.ops, self.pending
+
+
+#: the op collector, for stage files
+Ops = _Ops
 
 
 def _producer(fifos, r):

@@ -72,7 +72,8 @@ def compare(bm: Benchmark, ctx: Context, res: dict, control=False) -> dict:
     """Every number the check compares for the window's result ``res``."""
     designs = {name: bm.design(name) for name, _ in res["answers"]}
     comparison = check.compare(res["answers"], designs, ctx.seed,
-                               control=control, **ctx.mix["check"])
+                               control=control, bench=bm.bench,
+                               **ctx.mix["check"])
     comparison.update(res.get("comparison", {}))
     return comparison
 

@@ -3,13 +3,16 @@ configuration and its traffic mix; each lives in files of its own.
 
 * configuration: the ``file`` that ``BENCHMARK.json`` gives it, under
   ``chipbench/configs/``; design stage lists in ``configs/designs/``;
+* stage builder: a stage list's ``"stage": "<module>.<builder>"`` that is
+  not one of the reference's built-in stages is
+  ``chipbench/stages/<module>.py``'s ``<builder>(fifos, rec)``;
 * traffic mix: ``chipbench/traffic/<mix>.json``, driven by the generator
   ``chipbench/traffic/<kind>.py`` that the mix's ``kind`` names;
 * per-layer metric: ``chipbench/metrics/<metric>.py``, whose ``read(run)``
   returns the value or None when the run holds nothing to read.
 
-A later cell, mix or metric is added as new files and entries; nothing
-here names one.
+A later cell, mix, metric or stage builder is added as new files and
+entries; nothing here names one.
 """
 
 from __future__ import annotations
@@ -44,10 +47,27 @@ def _load_module(path: str, name: str):
     return mod
 
 
+def stage(bench: str, name: str) -> Callable:
+    """The stage builder ``name`` (``<module>.<builder>``) from
+    ``<bench>/stages/<module>.py``."""
+    module, _, builder = name.rpartition(".")
+    if not module:
+        raise SpecError(f"no stage {name!r}: built-in stages are "
+                        "plain names, file stages <module>.<builder>")
+    mod = _load_module(os.path.join(bench, "stages", f"{module}.py"),
+                       "chipbench_stages_" + module.replace(".", "_"))
+    try:
+        return getattr(mod, builder)
+    except AttributeError:
+        raise SpecError(f"no stage builder {builder!r} in "
+                        f"chipbench/stages/{module}.py")
+
+
 class Benchmark:
     """``BENCHMARK.json`` with lookups by name; ``root`` is the checkout
-    and ``bench`` the directory that holds configs, traffic and metrics
-    (both overridable, which is how the tests add a throwaway mix)."""
+    and ``bench`` the directory that holds configs, traffic, metrics and
+    stages (both overridable, which is how the tests add a throwaway mix
+    or stage)."""
 
     def __init__(self, root: str = ROOT, bench: str = BENCH):
         self.root, self.bench = root, bench

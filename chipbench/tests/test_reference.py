@@ -1,11 +1,15 @@
 """The plain reference against the program, on the CPU.
 
-The design files must be the benchmark's own table of the published
-kernels (``tools/stage_lists.py``).  The reference must describe the
-same designs as the program (its op streams equal the program's trace,
-task by task) and give the same answers as the program's exact numpy
-worklist on seeded rows, deadlocks included; its bfloat16 control must
-not.
+A design file without a ``program`` entry is a Stream-HLS kernel: it
+must be the benchmark's own table of the published kernels
+(``tools/stage_lists.py``) and describe ``make_design(name)``.  A file
+with one (a data-dependent design, whose stages are found by file under
+``stages/``) must be its writer's output for the entry's arguments
+(``tools/pna_design.py``) and describe the design that the entry's call
+builds.  The reference must describe the same designs as the program
+(its op streams equal the program's trace, task by task) and give the
+same answers as the program's exact numpy worklist on seeded rows,
+deadlocks included; its bfloat16 control must not.
 """
 
 import glob
@@ -16,32 +20,59 @@ import numpy as np
 import pytest
 
 from bench import reference as ref
-from tools import stage_lists
+from stages import pna
+from tools import pna_design, stage_lists
 
 DESIGNS = sorted(glob.glob(os.path.join(
     os.path.dirname(__file__), "..", "configs", "designs", "*.json")))
+#: what writes a design file with a ``program`` entry, by its call
+WRITERS = {pna_design.CALL: pna_design.text_of}
+#: PNA graphs beyond the design files' (nodes, edges, lanes, seed); the
+#: last is the Cora citation graph's size, 133,192 events
+PNA_GRAPHS = [(50, 300, 2, 3), (200, 1000, 8, 12345),
+              (97, 97, 1, 3000000019), (2708, 10556, 4, 7)]
 
 
 def _spec(path):
+    if isinstance(path, tuple):
+        return pna_design.design("flowgnn_pna", *path)
     with open(path) as f:
         return json.load(f)
+
+
+def _id(path):
+    if isinstance(path, tuple):
+        return "pna-" + "-".join(map(str, path))
+    return os.path.basename(path)
+
+
+def _program(spec):
+    """The program's design that ``spec`` describes."""
+    import repro.designs
+    if "program" in spec:
+        call = spec["program"]
+        return getattr(repro.designs, call["call"])(**call["args"])
+    return repro.designs.make_design(spec["name"])
 
 
 @pytest.mark.parametrize("path", DESIGNS, ids=os.path.basename)
 def test_files_follow_the_table(path):
     with open(path) as f:
         text = f.read()
-    name = json.loads(text)["name"]
-    assert text == stage_lists.dumps(stage_lists.stage_list(name))
+    spec = json.loads(text)
+    if "program" in spec:
+        assert text == WRITERS[spec["program"]["call"]](spec)
+    else:
+        assert text == stage_lists.dumps(stage_lists.stage_list(
+            spec["name"]))
 
 
-@pytest.mark.parametrize("path", DESIGNS, ids=os.path.basename)
+@pytest.mark.parametrize("path", DESIGNS + PNA_GRAPHS, ids=_id)
 def test_ops_match_program_trace(path):
     from repro.core.tracer import collect_trace
-    from repro.designs import make_design
     spec = _spec(path)
     design = ref.Design(spec)
-    program = make_design(spec["name"])
+    program = _program(spec)
     assert design.widths == program.widths()
     trace = collect_trace(program)
     assert len(trace.tasks) == len(design.tasks)
@@ -64,17 +95,28 @@ def _rows(g, n, seed):
     return np.concatenate([hot, wide])
 
 
+def _bound_rows(g, n, seed):
+    """Each FIFO at its upper bound, or with chance 1/4 drawn uniformly
+    below it: a data-dependent design deadlocks on nearly every row
+    ``_rows`` draws, and runs on some of these."""
+    rng = np.random.default_rng(seed)
+    u = np.asarray(g.upper_bounds, dtype=np.int64)
+    drawn = rng.integers(1, u + 1, size=(n, u.size))
+    return np.where(rng.uniform(size=(n, u.size)) < 0.75, u, drawn)
+
+
 @pytest.mark.parametrize("path", DESIGNS, ids=os.path.basename)
 def test_answers_match_program(path):
     from repro.core import build_simgraph
     from repro.core.config import EvalConfig
     from repro.core.simulate import BatchedEvaluator
-    from repro.designs import make_design
     spec = _spec(path)
     design = ref.Design(spec)
-    g = build_simgraph(make_design(spec["name"]))
-    rows = np.concatenate([_rows(g, 6, seed=7),
-                           np.full((1, g.n_fifos), 2, dtype=np.int64)])
+    g = build_simgraph(_program(spec))
+    rows = [_rows(g, 6, seed=7), np.full((1, g.n_fifos), 2, dtype=np.int64)]
+    if "program" in spec:
+        rows.append(_bound_rows(g, 8, seed=7))
+    rows = np.concatenate(rows)
     lat, bram, dead = BatchedEvaluator(
         g, EvalConfig(backend="numpy")).evaluate(rows)
     got = [ref.answer(design, r) for r in rows]
@@ -84,15 +126,27 @@ def test_answers_match_program(path):
     assert not all(dead)
     if spec["name"] == "k15mmtree_relu":
         assert dead[-1]       # the paper's Baseline-Min deadlock
+    if "program" in spec:
+        assert any(dead)
 
 
-def test_bfloat16_control_fails():
-    spec = _spec([p for p in DESIGNS if p.endswith("gemm.json")][0])
+@pytest.mark.parametrize("name", ["gemm", "flowgnn_pna"])
+def test_bfloat16_control_fails(name):
+    spec = _spec([p for p in DESIGNS if p.endswith(f"/{name}.json")][0])
     design = ref.Design(spec)
     depths = [64] * design.n_fifos
     exact = ref.answer(design, depths)
     control = ref.answer(design, depths, round_to=ref.bfloat16)
     assert not exact[2] and control[0] != exact[0]
+
+
+@pytest.mark.parametrize("nodes, edges, seed", [
+    (64, 256, 7), (50, 300, 3), (1000, 5000, 3000000019),
+    (2708, 10556, 7)])
+def test_pna_graph_matches_program(nodes, edges, seed):
+    from repro.designs.ddcf import _random_graph
+    assert pna.graph(nodes, edges, seed) == _random_graph(nodes, edges,
+                                                          seed)
 
 
 def test_frontier():

@@ -238,11 +238,14 @@ def stage_list(name: str) -> Dict:
 
 
 def dumps(spec: Dict) -> str:
-    """One stream or stage to a line, as the files are laid out."""
+    """One stream or stage to a line, as the files are laid out; a
+    data-dependent design's ``program`` entry on a line after its name."""
     def block(key):
         rows = ",\n".join("  " + json.dumps(x) for x in spec[key])
         return f' "{key}": [\n{rows}\n ]'
-    return (f'{{\n "name": {json.dumps(spec["name"])},\n'
+    program = (f' "program": {json.dumps(spec["program"])},\n'
+               if "program" in spec else "")
+    return (f'{{\n "name": {json.dumps(spec["name"])},\n{program}'
             f'{block("streams")},\n{block("tasks")}\n}}\n')
 
 
