@@ -14,7 +14,8 @@ Owns the three batch-shaping concerns that used to be tangled into
    fixpoint converged: deadlocks never converge by construction, and rare
    feasible rows converge slowly) are re-solved exactly by the worklist
    arbiter, counted in ``stats.n_fallbacks``, timed in
-   ``stats.worklist_s``.
+   ``stats.worklist_s``.  Two or more such rows fan out over the
+   process's worklist lanes (:class:`EscalationLanes`).
 
 Each layer opens a ``fifo.<name>`` profiler span (:mod:`repro.core.spans`)
 once per call: ``raw``, ``worklist``, ``rung.<tag>``, and the
@@ -42,7 +43,11 @@ so this module stays importable in numpy-only worker processes.
 from __future__ import annotations
 
 import dataclasses
+import itertools
+import os
+import threading
 import time
+import weakref
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -54,6 +59,109 @@ from repro.core.simgraph import SimGraph
 from repro.core.spans import span
 
 BUCKETS = (1, 8, 32, 128, 512, 2048)
+
+
+def escalation_lanes() -> int:
+    """Worklist lanes beside the calling thread: the CPUs this process
+    may run on (its affinity mask, else the host's count), less one for
+    the caller, which solves a chunk itself."""
+    try:
+        cpus = len(os.sched_getaffinity(0))
+    except AttributeError:  # no affinity masks on this platform
+        cpus = os.cpu_count() or 1
+    return cpus - 1
+
+
+class EscalationLanes:
+    """Worklist lanes that solve escalated rows beside the calling
+    thread: one :class:`~repro.core.campaign.pool.WorkerPool` for every
+    :class:`DispatchPolicy` of the process (:data:`LANES`).
+
+    The pool starts at the first escalation of two rows or more and grows
+    to the most lanes an escalation has asked for: at most one lane a row
+    beyond the caller's, never more than :func:`escalation_lanes`.  A
+    policy's graph rides to a lane with its first rows there.  The pool
+    stops when the last policy that used it leaves (is closed or
+    collected), and after any escalation that raised, so no answer of a
+    failed batch is left in a pipe for the next one to read.  A thread
+    that finds the lanes busy with another thread's rows solves its own
+    inline.  ``faults`` is the pool's fault plan (chaos testing).
+    """
+
+    def __init__(self, faults=None):
+        self.faults = faults
+        self.pool = None
+        self._busy = threading.Lock()
+        self._keys = itertools.count()
+        self._users: set = set()   # keys of the policies still here
+        self._gone: list = []      # keys to drop from the lanes
+
+    def join(self) -> str:
+        """A key for one policy's graph on the lanes."""
+        key = f"graph{next(self._keys)}"
+        self._users.add(key)
+        return key
+
+    def leave(self, key: str) -> None:
+        """``key``'s policy is gone: its graph leaves the lanes at the
+        next escalation, and the last to leave stops the pool.  Runs from
+        a finalizer, so it never waits for the lanes."""
+        self._users.discard(key)
+        self._gone.append(key)
+        if not self._users and self._busy.acquire(blocking=False):
+            try:
+                self._stop()
+            finally:
+                self._busy.release()
+
+    def solve(self, key: str, worklist: WorklistBackend, m: np.ndarray,
+              stats=None) -> Optional[Tuple[np.ndarray, np.ndarray]]:
+        """Exact ``(latency, deadlock)`` of the rows ``m``, in row order,
+        from contiguous chunks: the first on this thread, one on each
+        lane.  None where no lane can help: one row, one CPU, or the
+        lanes busy.  A lane's latency on a deadlocked row is -1, the
+        caller's is not; ``dispatch`` sets both to -1."""
+        n_lanes = min(escalation_lanes(), m.shape[0] - 1)
+        if n_lanes < 1 or not self._busy.acquire(blocking=False):
+            return None
+        try:
+            pool = self._ready(n_lanes)
+            pool.add_graph(key, worklist.g)
+            chunks = np.array_split(np.arange(m.shape[0]), n_lanes + 1)
+            handle = pool.submit([(w, key, m[c], None)
+                                  for w, c in enumerate(chunks[1:])])
+            lat, _, status = worklist.evaluate(m[chunks[0]])
+            done = pool.collect(handle)
+        except BaseException:
+            self._stop()
+            raise
+        finally:
+            self._busy.release()
+        if stats is not None:
+            stats.worklist_lane_rows += m.shape[0] - chunks[0].size
+        return (np.concatenate([lat] + [r[0] for r in done]),
+                np.concatenate([status == DEADLOCK] + [r[2] for r in done]))
+
+    def _ready(self, n_lanes: int):
+        """The pool, with at least ``n_lanes`` lanes and no graph of a
+        policy that left."""
+        if self.pool is None:
+            from repro.core.campaign.pool import WorkerPool
+            self.pool = WorkerPool(n_lanes, faults=self.faults)
+        self.pool.grow(n_lanes)
+        while self._gone:
+            self.pool.drop_graph(self._gone.pop())
+        return self.pool
+
+    def _stop(self) -> None:
+        if self.pool is not None:
+            self.pool.close()
+            self.pool = None
+        self._gone.clear()
+
+
+#: the worklist lanes of this process
+LANES = EscalationLanes()
 
 
 def _count_iters(stats, backend: EvalBackend, rows: int) -> None:
@@ -76,14 +184,24 @@ class DispatchPolicy:
     rounds every padded batch up to a shard multiple so the sharded
     evaluators split rows evenly across devices without growing their
     jit cache beyond the bucketed shape set.
+
+    A dispatch that leaves two or more rows UNRESOLVED solves them on
+    ``lanes`` (default :data:`LANES`, the process's own) and this
+    thread together.  The policy joins the lanes at its first such
+    escalation and leaves them with :meth:`close`, or when it is
+    collected.
     """
 
     def __init__(self, worklist: WorklistBackend,
                  buckets: Tuple[int, ...] = BUCKETS,
-                 shard_multiple: int = 1):
+                 shard_multiple: int = 1,
+                 lanes: Optional[EscalationLanes] = None):
         self.worklist = worklist
         self.buckets = tuple(buckets)
         self.shard_multiple = max(1, int(shard_multiple))
+        self.lanes = lanes
+        self._lane_key: Optional[str] = None
+        self._leave = None
 
     def bucket_size(self, c: int) -> Optional[int]:
         return next((b for b in self.buckets if b >= c), None)
@@ -116,13 +234,34 @@ class DispatchPolicy:
         unresolved = np.flatnonzero(status == UNRESOLVED)
         if unresolved.size:
             with span("worklist", stats, "worklist_s"):
-                wl_lat, _, wl_status = self.worklist.evaluate(m[unresolved])
-            lat[unresolved] = wl_lat
-            dead[unresolved] = wl_status == DEADLOCK
+                lat[unresolved], dead[unresolved] = self._escalate(
+                    m[unresolved], stats)
             if stats is not None:
                 stats.n_fallbacks += int(unresolved.size)
         lat = np.where(dead, -1, lat)
         return lat, bram, dead
+
+    def _escalate(self, m: np.ndarray, stats=None
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+        """Exact ``(latency, deadlock)`` of the rows ``m``, in row order:
+        on the lanes where they help, else on this thread."""
+        if m.shape[0] > 1:
+            lanes = self.lanes or LANES
+            if self._lane_key is None:
+                self._lane_key = lanes.join()
+                self._leave = weakref.finalize(self, lanes.leave,
+                                               self._lane_key)
+            got = lanes.solve(self._lane_key, self.worklist, m, stats)
+            if got is not None:
+                return got
+        lat, _, status = self.worklist.evaluate(m)
+        return lat, status == DEADLOCK
+
+    def close(self) -> None:
+        """Leave the worklist lanes; the next escalation joins again."""
+        if self._leave is not None:
+            self._leave()
+        self._lane_key = self._leave = None
 
 
 class RungCascade:

@@ -13,7 +13,10 @@ this process — children then inherit the campaign's already-built graphs
 and worklist tables for free (the whole evaluation chain is jax-free, so
 there are no XLA threads to trip over).  Once jax IS loaded (hetero mode,
 test suites), the pool falls back to ``spawn``: clean ~0.3 s numpy-only
-interpreter per worker that re-traces its designs on first use.
+interpreter per worker.  A graph a lane lacks (any graph under spawn, one
+added after the fork under fork) rides to the lane pickled with its first
+job there: its arrays, since a design's task programs are closures and
+stay behind.  A lane re-traces by name only a design it was never given.
 
 Supervision: a lane that crashes or stops answering within
 ``recv_timeout_s`` is detected (EOF on its pipe, or the recv deadline
@@ -30,6 +33,8 @@ worker failures.  Fault schedules for chaos testing are injected via
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
 import multiprocessing as mp
 import os
 import sys
@@ -100,7 +105,14 @@ def _worker_main(conn, max_iters: int, graphs: Optional[Dict] = None,
             msg = conn.recv()
             if msg is None:
                 break
-            name, depths, base = msg
+            name, depths, base, graph = msg
+            if graph is not None:
+                graphs[name] = graph
+                designs.pop(name, None)
+            if depths is None:  # the design is gone: forget it
+                graphs.pop(name, None)
+                designs.pop(name, None)
+                continue
             if faults:
                 check_worker_faults(faults, n_jobs)
             n_jobs += 1
@@ -123,6 +135,24 @@ def _worker_main(conn, max_iters: int, graphs: Optional[Dict] = None,
         conn.close()
 
 
+@contextlib.contextmanager
+def _main_script_hidden():
+    """Keep a spawned lane from running the caller's main script again:
+    spawn re-runs a script given by path in every child, and a script
+    without a ``__main__`` guard would start its whole work over there.
+    Lanes run only this package's code, so they never need it."""
+    main = sys.modules.get("__main__")
+    path = getattr(main, "__file__", None)
+    if path is None or getattr(main, "__spec__", None) is not None:
+        yield   # no script, or a module spawn imports by name
+        return
+    del main.__file__
+    try:
+        yield
+    finally:
+        main.__file__ = path
+
+
 def pick_start_method() -> str:
     """fork when it is free of XLA-thread hazards, else spawn."""
     if "fork" in mp.get_all_start_methods() and "jax" not in sys.modules:
@@ -139,8 +169,9 @@ class WorkerPool:
         max_iters: fixpoint cap forwarded to each worker's evaluator.
         start_method: force ``fork``/``spawn``; default picks.
         graphs: prebuilt ``{name: SimGraph}`` — rides to fork children
-            via copy-on-write, and backs the parent's inline-escalation
-            evaluators under either start method.
+            via copy-on-write, to spawn children with their first job of
+            that name, and backs the parent's inline-escalation
+            evaluators.  :meth:`add_graph` adds one later.
         faults: a :class:`FaultPlan` to exercise recovery paths
             (chaos testing only; None = no injection).
         recv_timeout_s: silence window after which a lane is declared
@@ -166,17 +197,15 @@ class WorkerPool:
         self.max_retries = int(max_retries)
         #: how long close() waits for a clean exit before escalating
         self.join_timeout_s = 5.0
-        self._graphs = graphs or {}
-        # graphs can only ride along through fork's copy-on-write pages;
-        # spawn workers rebuild their designs by name on first use
-        self._payload = self._graphs if self.start_method == "fork" \
-            else None
+        self._graphs = dict(graphs or {})
         self._ctx = mp.get_context(self.start_method)
         self._local: Dict[str, _WorkerDesign] = {}  # inline escalation
         self.stats = {"respawns": 0, "requeued": 0, "escalated": 0,
                       "recovery_s": 0.0}
         self._pipes: List = [None] * self.n_workers
         self._procs: List = [None] * self.n_workers
+        #: per lane, the names whose graphs the lane holds
+        self._shipped: List[set] = [set() for _ in range(self.n_workers)]
         for w in range(self.n_workers):
             self._spawn_lane(w)
 
@@ -184,14 +213,53 @@ class WorkerPool:
     def _spawn_lane(self, w: int):
         wf = self.faults.worker_payload(w) if self.faults else None
         parent_conn, child_conn = self._ctx.Pipe()
+        fork = self.start_method == "fork"
         proc = self._ctx.Process(
             target=_worker_main,
-            args=(child_conn, self.max_iters, self._payload, wf),
+            args=(child_conn, self.max_iters,
+                  dict(self._graphs) if fork else None, wf),
             daemon=True)
-        proc.start()
+        if fork:
+            proc.start()
+        else:
+            with _main_script_hidden():
+                proc.start()
         child_conn.close()
         self._pipes[w] = parent_conn
         self._procs[w] = proc
+        self._shipped[w] = set(self._graphs) if fork else set()
+
+    def grow(self, n_workers: int) -> None:
+        """Start lanes until there are ``n_workers``; never stops one."""
+        for w in range(self.n_workers, int(n_workers)):
+            self._pipes.append(None)
+            self._procs.append(None)
+            self._shipped.append(set())
+            self._spawn_lane(w)
+        self.n_workers = max(self.n_workers, int(n_workers))
+
+    def add_graph(self, name: str, graph) -> None:
+        """Let every lane solve ``name`` on ``graph``: a lane is shipped
+        it with its next job of that name, unless it holds it already."""
+        if self._graphs.get(name) is graph:
+            return
+        self._graphs[name] = graph
+        self._local.pop(name, None)
+        for held in self._shipped:
+            held.discard(name)
+
+    def drop_graph(self, name: str) -> None:
+        """Forget ``name`` in the parent and in every lane that holds it.
+        Call it between rounds, with no job in flight."""
+        self._graphs.pop(name, None)
+        self._local.pop(name, None)
+        for w, held in enumerate(self._shipped):
+            if name in held:
+                held.discard(name)
+                try:
+                    self._pipes[w].send((name, None, None, None))
+                except (BrokenPipeError, OSError):  # the next job revives it
+                    pass
 
     def _revive(self, w: int):
         """Kill whatever is left of lane ``w`` and spawn a replacement."""
@@ -251,11 +319,21 @@ class WorkerPool:
             if f is not None:
                 time.sleep(f.value)
         try:
-            self._pipes[w].send((name, depths, base))
+            self._pipes[w].send((name, depths, base, self._ship(w, name)))
         except (BrokenPipeError, OSError):
             self._recover(handle, w)
-            self._pipes[w].send((name, depths, base))
+            self._pipes[w].send((name, depths, base, self._ship(w, name)))
         handle["per_worker"].setdefault(w, deque()).append(j)
+
+    def _ship(self, w: int, name: str):
+        """The graph lane ``w`` still lacks for ``name``, or None: its
+        arrays, without the design, whose task programs are closures (a
+        solve reads only the arrays)."""
+        g = self._graphs.get(name)
+        if g is None or name in self._shipped[w]:
+            return None
+        self._shipped[w].add(name)
+        return dataclasses.replace(g, design=None)
 
     def _recover(self, handle: Dict, w: int):
         """Lane ``w`` failed: respawn it and re-dispatch its in-flight

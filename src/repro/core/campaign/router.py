@@ -79,11 +79,6 @@ class RoundRouter:
         self.designs = designs
         self.pool = pool
         self.hetero = hetero
-        #: design keys whose rows must evaluate on lane 0 (this process)
-        #: even when a pool is attached — used for designs the pool's
-        #: worker processes cannot rebuild (custom Design objects that
-        #: ``make_design`` does not know)
-        self.inline_only: set = set()
 
     @property
     def n_lanes(self) -> int:
@@ -174,7 +169,7 @@ class RoundRouter:
         main_incr: List[RoutedRequest] = []
         for p in incr:
             rows = p.miss_rows
-            lane = 0 if p.key in self.inline_only else p.lane
+            lane = p.lane
             load[lane] += rows.size * self.designs[p.key].graph.n_events
             if lane == 0:
                 main_incr.append(p)
@@ -189,14 +184,10 @@ class RoundRouter:
         for mi, (name, _plist, uniq, _inv) in enumerate(merged):
             cost = self.designs[name].graph.n_events
             sel: Dict[int, List[int]] = {}
-            if name in self.inline_only:
-                load[0] += cost * uniq.shape[0]
-                sel[0] = list(range(uniq.shape[0]))
-            else:
-                for r in range(uniq.shape[0]):
-                    lane = int(np.argmin(load))
-                    load[lane] += cost
-                    sel.setdefault(lane, []).append(r)
+            for r in range(uniq.shape[0]):
+                lane = int(np.argmin(load))
+                load[lane] += cost
+                sel.setdefault(lane, []).append(r)
             for lane, rsel in sel.items():
                 rsel = np.asarray(rsel)
                 if lane == 0:

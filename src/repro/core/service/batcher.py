@@ -85,7 +85,6 @@ class CrossSessionBatcher:
         #: EWMA of the wall time of one batched round, feeding the
         #: overload replies' retry-after estimate
         self.round_ewma_s = 0.0
-        self._pool_designs: set = set()   # designs the pool was built with
 
     @property
     def n_lanes(self) -> int:
@@ -95,12 +94,9 @@ class CrossSessionBatcher:
         """Keep the hetero envelope / worker pool aware of ``name``.
 
         Hetero mode extends the dispatcher's operand envelope in place.
-        Pool mode must keep every worker able to evaluate the design:
-        custom ``Design`` objects are pinned to lane 0 (a fresh worker
-        process cannot rebuild them by name), and a *named* design that
-        arrives after the pool exists rebuilds the pool so the workers
-        pick up its graph — sessions are rare next to rounds, so the
-        respawn cost is noise.
+        Pool mode hands the pool the design's graph, which each worker
+        is shipped with its first job of the design, so custom ``Design``
+        objects run on every lane like named ones.
         """
         adv = self.registry[name]
         if self.want_hetero:
@@ -112,21 +108,13 @@ class CrossSessionBatcher:
             self.router.hetero.add_design(
                 name, adv.graph, getattr(adv.evaluator, "_worklist", None))
         elif self.workers > 0:
-            if name in self.registry.custom_names:
-                self.router.inline_only.add(name)
-            elif (self.router.pool is None
-                  or name not in self._pool_designs):
+            if self.router.pool is None:
                 from repro.core.campaign.pool import WorkerPool
-                if self.router.pool is not None:
-                    self.router.pool.close()
-                self._pool_designs = {
-                    k for k in self.registry
-                    if k not in self.registry.custom_names}
                 self.router.pool = WorkerPool(
                     self.workers, max_iters=self.registry.max_iters,
-                    graphs={k: self.registry[k].graph
-                            for k in self._pool_designs},
-                    faults=self.faults)
+                    graphs={name: adv.graph}, faults=self.faults)
+            else:
+                self.router.pool.add_graph(name, adv.graph)
 
     def step(self, sessions: List[Session]) -> int:
         """One cross-session round over the given *running* sessions.

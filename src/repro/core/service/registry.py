@@ -41,8 +41,8 @@ class DesignRegistry:
         self.advisor_kwargs = dict(advisor_kwargs or {})
         self._advisors: Dict[str, FifoAdvisor] = {}
         #: names registered with an explicit Design object — these are
-        #: NOT rebuildable via ``make_design`` in a fresh process, which
-        #: matters to engines that re-trace by name (the worker pool)
+        #: NOT rebuildable via ``make_design`` in a fresh process, so a
+        #: snapshot skips them
         self.custom_names: set = set()
         #: set by the snapshot loader: {"restored": [...],
         #: "quarantined": {name: reason}} — None until a restore ran

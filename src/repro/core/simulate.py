@@ -73,6 +73,7 @@ class BatchStats:
     n_cond_fail: int = 0      # rung attempts whose certificate failed
     wall_s: float = 0.0
     worklist_s: float = 0.0   # escalating UNRESOLVED rows to the worklist
+    worklist_lane_rows: int = 0  # of those rows, solved on worklist lanes
     raw_rows: int = 0         # real rows of fifo_eval_raw launches
     raw_row_iters: int = 0    # their Jacobi iterations (the kernel's lane 3)
     raw_tile_iters: int = 0   # 8-row block iterations x E_pad / 128 vregs
@@ -369,6 +370,12 @@ class BatchedEvaluator:
     @property
     def incr_stats(self):
         return self._worklist.incr_stats
+
+    def close(self) -> None:
+        """Leave the process's worklist lanes, if this evaluator used
+        them (collecting it does the same); the last to leave stops
+        them."""
+        self.dispatch.close()
 
     def condensation_info(self) -> list:
         """Per-rung condensation summary for reports: tag, raw/condensed

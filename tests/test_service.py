@@ -136,9 +136,9 @@ def test_progress_events_stream_frontier_deltas():
 
 
 def test_pooled_service_handles_late_and_custom_designs():
-    """Worker-pool mode: a design opened after the pool exists (rebuild)
-    and a custom Design object (pinned inline — fresh worker processes
-    cannot rebuild it by name) both evaluate correctly."""
+    """Worker-pool mode: a design opened after the pool exists and a
+    custom Design object (which a fresh worker process could not rebuild
+    by name) both reach the workers as graphs and evaluate correctly."""
     from repro.core.design import Design
 
     def build_design():
@@ -164,12 +164,13 @@ def test_pooled_service_handles_late_and_custom_designs():
                                  budget=40, seed=0)
         late = svc.open_session("FeedForward",
                                 optimizer="grouped_random",
-                                budget=40, seed=1)       # pool rebuild
+                                budget=40, seed=1)       # graph added
         custom = svc.open_session("qs", design_obj=build_design(),
                                   optimizer="grouped_random",
-                                  budget=40, seed=2)     # inline-only
-        assert "qs" in svc.batcher.router.inline_only
-        assert svc.batcher.router.pool is not None
+                                  budget=40, seed=2)     # shipped, too
+        pool = svc.batcher.router.pool
+        assert pool is not None
+        assert pool._graphs["qs"] is svc.registry["qs"].graph
         svc.run_until_idle()
         assert {s.state for s in (first, late, custom)} == {"done"}
         assert_identical(svc.result(first.id),
